@@ -2,54 +2,51 @@
 
 (a/b) sparse group-join vs dense straw man for RID=RID and CID=RID;
 (c)   cost-model validation: the partitioner's predicted communication is
-      compared against XLA-measured collective bytes from a real lowered
-      distributed join on an 8-worker host mesh (subprocess, so the main
-      process keeps its single-device view).
+      compared against XLA-measured collective bytes of a distributed join
+      compiled for a described 8-chip TPU v5e slice, in this process.
 """
-import json
 import os
-import subprocess
-import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from benchmarks.common import row, sparse, timeit
+from repro.analysis.hlo import parse_hlo_module
 from repro.core import cost as costmod
 from repro.core.joins import d2d_dense, d2d_sparse
 from repro.core.matrix import BlockMatrix
 from repro.core.predicates import Field, parse_join
 from repro.core.sparsity import product_merge
 
-_PROBE = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, sys
-sys.path.insert(0, "src")
-import jax, jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.analysis.hlo import parse_hlo_module
 
-mesh = Mesh(np.array(jax.devices()).reshape(8), ("workers",))
-M = 4096
-out = {}
-for tag, (spec_a, spec_b) in {
-    "rr": (P("workers", None), P("workers", None)),
-    "rc": (P("workers", None), P(None, "workers")),
-}.items():
-    def join(a, b):
-        a = jax.lax.with_sharding_constraint(a, NamedSharding(mesh, spec_a))
-        b = jax.lax.with_sharding_constraint(b, NamedSharding(mesh, spec_b))
-        b = jax.lax.with_sharding_constraint(b, NamedSharding(mesh, spec_a))
-        return a * b
-    sd = jax.ShapeDtypeStruct((M, M), jnp.float32)
-    with mesh:
-        comp = jax.jit(join).lower(sd, sd).compile()
-    stats = parse_hlo_module(comp.as_text())
-    out[tag] = stats.collective_bytes
-print(json.dumps(out))
-"""
+def _measure_collective_bytes(n_workers: int = 8, m: int = 4096) -> dict:
+    """XLA-measured collective bytes of an aligned (r, r) and a
+    mispartitioned (r, c) distributed join, compiled for a described
+    ``n_workers``-chip TPU v5e slice (nothing runs, so the probe needs no
+    chip and takes none from a process that holds one)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name=f"v5e:2x{n_workers // 2}")
+    mesh = Mesh(np.array(topo.devices).reshape(n_workers), ("workers",))
+    out = {}
+    for tag, (spec_a, spec_b) in {
+        "rr": (P("workers", None), P("workers", None)),
+        "rc": (P("workers", None), P(None, "workers")),
+    }.items():
+        def join(a, b, spec_a=spec_a):
+            b = jax.lax.with_sharding_constraint(
+                b, NamedSharding(mesh, spec_a))
+            return a * b
+        sd_a = jax.ShapeDtypeStruct((m, m), jnp.float32,
+                                    sharding=NamedSharding(mesh, spec_a))
+        sd_b = jax.ShapeDtypeStruct((m, m), jnp.float32,
+                                    sharding=NamedSharding(mesh, spec_b))
+        comp = jax.jit(join).lower(sd_a, sd_b).compile()
+        out[tag] = parse_hlo_module(comp.as_text()).collective_bytes
+    return out
 
 
 def _kernel_backends(rng) -> None:
@@ -111,18 +108,10 @@ def run(rng) -> None:
         f"schemes=({best.scheme_a},{best.scheme_b})")
     row("fig11c_model_entries_rc", None, f"predicted={worst:.3g} entries")
 
-    env = dict(os.environ, PYTHONPATH="src")
-    try:
-        out = subprocess.run([sys.executable, "-c", _PROBE], env=env,
-                             capture_output=True, text=True, timeout=300,
-                             cwd=os.path.dirname(os.path.dirname(
-                                 os.path.abspath(__file__))))
-        measured = json.loads(out.stdout.strip().splitlines()[-1])
-        row("fig11c_xla_bytes_rr", None,
-            f"measured={measured['rr']:.3g}B (aligned schemes)")
-        row("fig11c_xla_bytes_rc", None,
-            f"measured={measured['rc']:.3g}B (mispartitioned)")
-        # the cost model's qualitative claim: aligned ≪ mispartitioned
-        assert measured["rr"] <= measured["rc"] * 0.2 + 1e3, measured
-    except (subprocess.TimeoutExpired, json.JSONDecodeError) as e:
-        row("fig11c_xla_bytes", None, f"probe_failed({e})")
+    measured = _measure_collective_bytes(n_workers)
+    row("fig11c_xla_bytes_rr", None,
+        f"measured={measured['rr']:.3g}B (aligned schemes)")
+    row("fig11c_xla_bytes_rc", None,
+        f"measured={measured['rc']:.3g}B (mispartitioned)")
+    # the cost model's qualitative claim: aligned ≪ mispartitioned
+    assert measured["rr"] <= measured["rc"] * 0.2 + 1e3, measured

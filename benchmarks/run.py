@@ -88,6 +88,8 @@ def _write_json(path: str, rows, only, wall_s: float) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import (
         bench_agg_gram, bench_cost_model, bench_cross_product,
         bench_dist_comm, bench_join_dims, bench_join_entries,

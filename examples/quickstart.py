@@ -43,7 +43,9 @@ def main():
     t = d2d.collect()
     print(f"D2D join → order-{t.order} tensor, {t.nnz} matches")
 
-    v2v = A.join(B, "VAL=VAL", lambda x_, y_: x_ + y_)
+    # a sparsity-inducing merge (f(0, y) = f(x, 0) = 0) joins only the
+    # nonzero entries; a non-inducing one would also pair every zero cell
+    v2v = A.join(B, "VAL=VAL", lambda x_, y_: x_ * y_)
     tv = v2v.collect()
     print(f"V2V (Bloom) join → order-{tv.order} tensor, {tv.nnz} matches")
 

@@ -103,6 +103,8 @@ class Computation:
             token = ""
             parts: List[str] = []
             for ch in op.operands_str:
+                if ch == ")" and depth == 0:
+                    break   # the operand list closes; attributes follow
                 if ch == "," and depth == 0:
                     parts.append(token)
                     token = ""

@@ -33,7 +33,8 @@ def reanalyze_cell(json_path: str) -> bool:
                         "train" if res["shape"].startswith("train")
                         else ("prefill" if res["shape"].startswith("prefill")
                               else "decode"))
-    roof = rl.analyze(stats, mf, res["n_chips"])
+    roof = rl.analyze(stats, mf, res["n_chips"],
+                      device_kind=res["device_kind"])
     res["hlo"] = dict(
         flops=stats.flops, dot_flops=stats.dot_flops,
         bytes_accessed=stats.bytes_accessed,

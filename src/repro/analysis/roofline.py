@@ -7,9 +7,11 @@ SPMD-partitioned, so every number is per-chip):
     memory     = HLO_bytes / HBM_bw
     collective = collective_bytes / ICI_bw
 
-Hardware constants (TPU v5e per the brief): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI. MODEL_FLOPS = 6·N·D (dense train) / 6·N_active·D (MoE)
-/ 2·N·D (inference) is reported alongside as the usefulness ratio.
+The peaks come from ``PEAKS``, keyed by the ``device_kind`` JAX reports
+for the chip the program was compiled for; a kind missing from the table
+is an error, never a default. MODEL_FLOPS = 6·N·D (dense train) /
+6·N_active·D (MoE) / 2·N·D (inference) is reported alongside as the
+usefulness ratio.
 """
 from __future__ import annotations
 
@@ -18,9 +20,29 @@ from typing import Dict, Optional
 
 from repro.analysis.hlo import HloStats
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # bytes / s / chip
-ICI_BW = 50e9             # bytes / s / link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float          # bf16 FLOP/s per chip
+    hbm_bw: float         # HBM bytes/s per chip
+    ici_bw: float         # interconnect bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e" system architecture:
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of inter-chip interconnect
+# per chip over 4 links (50 GB/s per link).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; KeyError when unlisted."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; have {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -41,11 +63,13 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze(stats: HloStats, model_flops_total: float, n_chips: int,
-            peak=PEAK_FLOPS, hbm=HBM_BW, ici=ICI_BW) -> Roofline:
+def analyze(stats: HloStats, model_flops_total: float, n_chips: int, *,
+            device_kind: str) -> Roofline:
+    pk = peaks_for(device_kind)
+    peak = pk.flops
     compute = stats.flops / peak
-    memory = stats.bytes_accessed / hbm
-    collective = stats.collective_bytes / ici
+    memory = stats.bytes_accessed / pk.hbm_bw
+    collective = stats.collective_bytes / pk.ici_bw
     terms = {"compute": compute, "memory": memory, "collective": collective}
     dominant = max(terms, key=terms.get)
     model_pc = model_flops_total / max(1, n_chips)

@@ -50,7 +50,7 @@ class Session:
     def __init__(self, block_size: int = 256, mode: str = "sparse",
                  use_bloom: bool = True, engine: str = "dag",
                  n_workers: Optional[int] = None, search: str = "memo",
-                 ledger=None, cost_model=None):
+                 ledger=None, cost_model=None, metrics=None):
         if engine not in ("dag", "tree"):
             raise ValueError(f"unknown engine {engine!r}")
         if search not in ("memo", "greedy"):
@@ -70,6 +70,10 @@ class Session:
         # its calibrated wall-time prediction into ``physical_cost``
         # (analytic-only when unset or unfitted for this device key)
         self.cost_model = cost_model
+        # optional ``obs.metrics.MetricsRegistry``: the DAG executor of
+        # every ``collect`` mirrors its counters into it as
+        # ``executor_<name>`` (staged / fallback / overflow counts)
+        self.metrics = metrics
         self._auto = 0
         self._mesh = None
         self._env_version = 0
@@ -137,7 +141,8 @@ class Session:
                                      block_size=self.block_size,
                                      use_bloom=self.use_bloom)
         pplan = self.physical_plan(plan)
-        ex = planmod.PlanExecutor(self.env, mesh=self.mesh)
+        ex = planmod.PlanExecutor(self.env, mesh=self.mesh,
+                                  metrics=self.metrics)
         import time
         t0 = time.perf_counter()
         out = ex.run(pplan)

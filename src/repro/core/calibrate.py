@@ -121,12 +121,9 @@ def device_key(backend: Optional[str] = None) -> str:
     """``platform:device_kind|kernel_backend`` — the coefficient-table
     key. Coefficients are machine- and backend-specific; a model fitted
     on one device kind must not predict for another."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        hw = f"{dev.platform}:{getattr(dev, 'device_kind', 'unknown')}"
-    except Exception:
-        hw = "cpu:unknown"
+    import jax
+    dev = jax.devices()[0]
+    hw = f"{dev.platform}:{dev.device_kind}"
     be = backend or os.environ.get("REPRO_KERNEL_BACKEND") or "default"
     return f"{hw}|{be}"
 

@@ -42,7 +42,8 @@ def _probe(fn, zero_first: bool) -> bool:
 
 
 def analyze_merge(merge: MergeFn) -> SparsityProfile:
-    """Sampling-based sparsity-inducing test (cached by merge-fn name)."""
+    """Sampling-based sparsity-inducing test, cached per merge function
+    (not per name: every callable ``Matrix.join`` wraps is named "f")."""
     return _analyze_cached(merge.name, merge.fn)
 
 
@@ -50,11 +51,11 @@ _CACHE = {}
 
 
 def _analyze_cached(name: str, fn) -> SparsityProfile:
-    prof = _CACHE.get(name)
+    prof = _CACHE.get((name, fn))
     if prof is None:
         prof = SparsityProfile(inducing_x=_probe(fn, True),
                                inducing_y=_probe(fn, False))
-        _CACHE[name] = prof
+        _CACHE[(name, fn)] = prof
     return prof
 
 

@@ -3,12 +3,11 @@
 Layout:
 
 * ``registry``       — logical kernel name → per-backend impls, selected at
-                       call time by runtime capability detection
-                       (``dense`` / ``pallas-interpret`` / ``pallas-tpu``).
-* ``compat``         — version-portability shims for the JAX experimental
-                       surface (``*CompilerParams`` renames, ``shard_map``
-                       relocation). The only module allowed to touch
-                       ``pltpu`` attribute names.
+                       plan and call time by runtime capability detection
+                       (``dense`` / ``pallas-interpret`` / ``pallas-tpu``
+                       / ``pallas-gpu``).
+* ``compat``         — probes whether the jaxlib carries the Triton
+                       lowering the ``pallas-gpu`` tier needs.
 * ``autotune``       — block-size autotuner keyed by
                        ``(kernel, shape-bucket, dtype, backend)`` with an
                        in-process + on-disk JSON cache.

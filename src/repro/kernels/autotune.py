@@ -81,12 +81,9 @@ def device_kind() -> str:
     it is scrubbed from free-form device-kind strings."""
     global _DEVICE_KIND
     if _DEVICE_KIND is None:
-        try:
-            import jax
-            d = jax.devices()[0]
-            kind = f"{d.platform}:{getattr(d, 'device_kind', 'unknown')}"
-        except Exception:
-            kind = "cpu:unknown"
+        import jax
+        d = jax.devices()[0]
+        kind = f"{d.platform}:{d.device_kind}"
         _DEVICE_KIND = kind.replace("|", "/").replace(" ", "_")
     return _DEVICE_KIND
 

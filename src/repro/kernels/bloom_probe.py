@@ -1,13 +1,15 @@
-"""Bloom-filter probe Pallas kernel (TPU target) for V2V Bloom-joins (§4.7).
+"""Bloom-filter probe Pallas kernel for V2V Bloom-joins (§4.7).
 
-The bitset (uint32 words, ≤512 KiB) lives fully in VMEM; probe values stream
-through in tiles. Hashing is the same multiply-shift family as
-``repro.core.bloom`` so filters built on one path probe on the other.
+The bitset (uint32 words, ≤512 KiB) lives fully in fast memory; probe
+values stream through in tiles. Hashing is the same multiply-shift family
+as ``repro.core.bloom`` so filters built on one path probe on the other.
 
-TPU note: the inner gather ``words[idx]`` is a dynamic VMEM gather. Mosaic
-supports 32-bit dynamic gathers from VMEM; on very old toolchains the
-fallback is the one-hot-matmul probe in ``ref.py`` — correctness is always
-validated against that oracle in interpret mode.
+The body runs under the interpreter and the Triton lowering. It has no
+TPU registration: the probe gathers bitset words at data-dependent
+indices from a 1-D array, and Mosaic lowers only 2-D gathers
+("Only 2D gather is supported", JAX 0.9.0), so on TPU the probe runs as
+the dense oracle's XLA gather. Correctness is validated against that
+oracle in interpret mode.
 """
 from __future__ import annotations
 
@@ -15,10 +17,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core.bloom import _MULTIPLIERS
-from repro.kernels import compat
-from repro.kernels.compat import pl
 
 
 def _hash(keys: jnp.ndarray, i: int, log2_bits: int) -> jnp.ndarray:
@@ -68,7 +69,5 @@ def bloom_probe_pallas(words: jnp.ndarray, vals: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n // bs, bs), jnp.bool_),
         interpret=interpret,
-        **compat.compiler_params_kwargs(
-            dimension_semantics=("parallel",)),
     )(words, vals2)
     return out.reshape(n)

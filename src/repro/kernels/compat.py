@@ -1,35 +1,12 @@
-"""Version-portability shims for the JAX experimental surface the kernels use.
+"""Capability probe for the optional Pallas Triton (GPU) lowering.
 
-JAX has renamed its Pallas TPU compiler-params class across releases
-(``pltpu.TPUCompilerParams`` → ``pltpu.CompilerParams``; older toolchains
-exposed ``pltpu.MosaicParams``) and promoted ``shard_map`` out of
-``jax.experimental``. Every kernel and training-substrate module resolves
-those names HERE and nowhere else, so the next rename is a one-line fix.
-
-Resolution is defensive in both directions: attribute names are probed in
-newest-first order, and constructor kwargs are filtered against the fields
-the resolved class actually declares, so passing a field a future release
-drops (or has not yet grown) degrades to defaults instead of raising.
+The kernels target the installed JAX (0.9.0): they import
+``jax.experimental.pallas`` and ``pltpu.CompilerParams`` directly, and the
+training substrate calls ``jax.shard_map`` directly. The one thing that
+still varies by installation is whether the jaxlib was built with the
+Triton lowering, which the ``pallas-gpu`` backend tier needs.
 """
 from __future__ import annotations
-
-import dataclasses
-import functools
-from typing import Any, Dict, Optional, Sequence
-
-import jax
-
-try:  # Pallas is optional: CPU-only wheels may ship without it. Kernel
-    # modules import ``pl`` from HERE (not jax.experimental) so they stay
-    # importable — and the dense backend reachable — on stripped wheels;
-    # only actually calling a pallas backend then fails.
-    from jax.experimental import pallas as pl  # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover - exercised only on stripped wheels
-    pl = None
-    pltpu = None
-    _HAS_PALLAS = False
 
 try:  # The Triton lowering ships only in GPU-enabled jaxlibs; resolving it
     # here (and nowhere else) is what lets the ``pallas-gpu`` backend tier
@@ -41,99 +18,8 @@ except ImportError:
     _HAS_TRITON = False
 
 
-def has_pallas() -> bool:
-    """True when ``jax.experimental.pallas`` imports on this installation."""
-    return _HAS_PALLAS
-
-
 def has_triton() -> bool:
     """True when the Pallas Triton (GPU) lowering imports here. Import
     success alone does not make the backend *runnable* — the registry
     additionally requires the default JAX backend to be a GPU."""
     return _HAS_TRITON
-
-
-# ---------------------------------------------------------------------------
-# pallas_call compiler params.
-# ---------------------------------------------------------------------------
-
-# Newest name first; the first attribute that exists wins.
-_COMPILER_PARAMS_NAMES = ("CompilerParams", "TPUCompilerParams",
-                          "MosaicParams")
-
-
-@functools.lru_cache(maxsize=1)
-def _compiler_params_cls():
-    if pltpu is None:
-        return None
-    for name in _COMPILER_PARAMS_NAMES:
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-def tpu_compiler_params(
-        *, dimension_semantics: Optional[Sequence[str]] = None,
-        **extra: Any):
-    """Instantiate this JAX's TPU compiler-params class, or None.
-
-    Unknown kwargs (fields a given release doesn't declare) are silently
-    dropped rather than raised, so callers can request newer knobs without
-    version-gating at every call site.
-    """
-    cls = _compiler_params_cls()
-    if cls is None:
-        return None
-    kwargs: Dict[str, Any] = dict(extra)
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    if dataclasses.is_dataclass(cls):
-        known = {f.name for f in dataclasses.fields(cls)}
-        kwargs = {k: v for k, v in kwargs.items() if k in known}
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        # non-dataclass params object with a stricter signature
-        return cls() if not kwargs else None
-
-
-def compiler_params_kwargs(
-        *, dimension_semantics: Optional[Sequence[str]] = None,
-        **extra: Any) -> Dict[str, Any]:
-    """``**splat``-ready ``pallas_call`` kwargs ({} when unsupported)."""
-    params = tpu_compiler_params(dimension_semantics=dimension_semantics,
-                                 **extra)
-    if params is None:
-        return {}
-    return {"compiler_params": params}
-
-
-# ---------------------------------------------------------------------------
-# shard_map.
-# ---------------------------------------------------------------------------
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: Optional[bool] = None):
-    """Portable ``shard_map``: ``jax.shard_map`` when present, else the
-    ``jax.experimental.shard_map`` original with kwargs translated
-    (``check_vma`` → ``check_rep``; ``axis_names`` → the ``auto`` complement).
-    """
-    modern = getattr(jax, "shard_map", None)
-    if modern is not None:
-        kw: Dict[str, Any] = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return modern(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

@@ -29,6 +29,12 @@ The dense oracle keeps the historical ``repeat``-then-gather formulation
 binary search per slot, which needs no cap-sized intermediate at all. The
 two agree on every slot below the true total; above it they may clamp to
 different (masked) segments, so parity is defined over valid slots only.
+
+The body runs under the interpreter and the Triton lowering. It has no
+TPU registration: its binary search and operand fetches are 1-D gathers
+at data-dependent indices, and Mosaic lowers only 2-D gathers ("Only 2D
+gather is supported", JAX 0.9.0), so on TPU the expansion runs as the
+dense oracle's XLA chain.
 """
 from __future__ import annotations
 
@@ -37,9 +43,7 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels import compat
-from repro.kernels.compat import pl
+from jax.experimental import pallas as pl
 
 
 def coo_expand_ref(ends: jnp.ndarray, delta: jnp.ndarray,
@@ -129,7 +133,5 @@ def coo_expand_pallas(ends: jnp.ndarray, delta: jnp.ndarray,
             jax.ShapeDtypeStruct((cap,), out_dtype),
         ],
         interpret=interpret,
-        **compat.compiler_params_kwargs(
-            dimension_semantics=("parallel",)),
     )(ends, delta, a_vals, a_coords, b_vals, b_coords)
     return idx, val

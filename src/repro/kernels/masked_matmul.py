@@ -10,6 +10,11 @@ Tiling: grid (mi, ni, ki); A tile (bm, bk), B tile (bk, bn), out tile
 (bm, bn) accumulated in-place in VMEM across the ki loop (the K dimension is
 the innermost, "arbitrary" grid axis; mi/ni are parallel). Block sizes are
 MXU-aligned (multiples of 128 for f32/bf16 inputs).
+
+The block mask rides in SMEM as a flat int32 scalar-prefetch operand: the
+kernel body reads it to gate the MXU work, and the A/B index maps read it
+to pin a dead tile's K panels to panel 0, so consecutive dead steps fetch
+nothing new.
 """
 from __future__ import annotations
 
@@ -17,19 +22,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-from repro.kernels.compat import pl
 
-
-def _kernel(mask_ref, a_ref, b_ref, out_ref, *, nk: int):
-    ki = pl.program_id(2)
+def _kernel(live_ref, a_ref, b_ref, out_ref, *, gn: int):
+    mi, ni, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(mask_ref[0, 0])
+    @pl.when(live_ref[mi * gn + ni] != 0)
     def _accum():
         out_ref[...] += jnp.dot(
             a_ref[...], b_ref[...], preferred_element_type=out_ref.dtype)
@@ -52,19 +56,28 @@ def masked_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, mask: jnp.ndarray,
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (a.shape, b.shape)
     gm, gn, gk = m // bm, n // bn, k // bk
     assert mask.shape == (gm, gn), (mask.shape, (gm, gn))
+    live = jnp.asarray(mask).reshape(-1).astype(jnp.int32)
+
+    def panel(mi, ni, ki, live):
+        return jnp.where(live[mi * gn + ni] != 0, ki, 0)
 
     out_dtype = jnp.promote_types(a.dtype, jnp.float32)
     return pl.pallas_call(
-        functools.partial(_kernel, nk=gk),
-        grid=(gm, gn, gk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda mi, ni, ki: (mi, ni)),      # mask
-            pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),    # A panel
-            pl.BlockSpec((bk, bn), lambda mi, ni, ki: (ki, ni)),    # B panel
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
+        functools.partial(_kernel, gn=gn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(gm, gn, gk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda mi, ni, ki, live:
+                             (mi, panel(mi, ni, ki, live))),    # A panel
+                pl.BlockSpec((bk, bn), lambda mi, ni, ki, live:
+                             (panel(mi, ni, ki, live), ni)),    # B panel
+            ],
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda mi, ni, ki, live: (mi, ni)),
+        ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
-        **compat.compiler_params_kwargs(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(mask, a, b).astype(a.dtype)
+    )(live, a, b).astype(a.dtype)

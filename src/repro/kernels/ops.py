@@ -1,10 +1,15 @@
 """Public kernel wrappers + registration of the built-in registry entries.
 
-Each logical kernel is registered under three backends (see
-``repro.kernels.registry``): the pure-jnp ``dense`` oracle from ``ref.py``,
-the Pallas body under the interpreter (``pallas-interpret``), and the
-compiled Mosaic kernel (``pallas-tpu``). The module-level functions keep
-the historical call-sites working (``force="ref"/"pallas"``) by translating
+Each logical kernel is registered under the pure-jnp ``dense`` oracle from
+``ref.py``, the Pallas body under the interpreter (``pallas-interpret``)
+and the Triton lowering (``pallas-gpu``) — see ``repro.kernels.registry``.
+The compiled Mosaic kernel (``pallas-tpu``) is registered for the bodies
+Mosaic compiles — ``masked_matmul``, ``merge_join`` and ``sddmm_agg``,
+planned where ``registry.accepts`` allows. ``bloom_probe`` and ``coo_expand``
+have none: their bodies gather at data-dependent indices from 1-D arrays,
+which Mosaic does not lower, so on TPU they resolve to the dense (XLA)
+path (``docs/kernels.md``). The module-level functions keep the
+historical call-sites working (``force="ref"/"pallas"``) by translating
 ``force`` to a backend and going through ``registry.dispatch``.
 
 Padding/alignment lives here, not in the kernel bodies: callers hand
@@ -31,18 +36,18 @@ from repro.kernels.sddmm_agg import sddmm_agg_pallas, sddmm_agg_ref
 Tiles = Optional[Dict[str, int]]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _force_to_backend(force: Optional[str]) -> Optional[str]:
-    """Translate the historical ``force`` arg to a registry backend."""
+def _force_to_backend(name: str, force: Optional[str]) -> Optional[str]:
+    """Translate the historical ``force`` arg to a registry backend:
+    ``"pallas"`` is the compiled kernel where this kernel has one for this
+    process, else its body under the interpreter."""
     if force is None:
         return None  # registry default: pallas-tpu on TPU, else dense
     if force == "ref":
         return registry.DENSE
     if force == "pallas":
-        return registry.TPU if _on_tpu() else registry.INTERPRET
+        compiled = (jax.default_backend() == "tpu"
+                    and registry.TPU in registry.get(name).impls)
+        return registry.TPU if compiled else registry.INTERPRET
     return force  # already a backend name
 
 
@@ -199,14 +204,6 @@ def _bloom_probe_interpret(words, vals, *, num_hashes: int = 3,
                                interpret=True)
 
 
-@registry.register("bloom_probe", registry.TPU)
-def _bloom_probe_tpu(words, vals, *, num_hashes: int = 3,
-                     log2_bits: int = 20, tiles: Tiles = None):
-    return _bloom_probe_pallas(words, vals, num_hashes=num_hashes,
-                               log2_bits=log2_bits, tiles=tiles,
-                               interpret=False)
-
-
 @registry.register("bloom_probe", registry.GPU)
 def _bloom_probe_gpu(words, vals, *, num_hashes: int = 3,
                      log2_bits: int = 20, tiles: Tiles = None):
@@ -249,13 +246,6 @@ def _coo_expand_interpret(ends, delta, a_vals, a_coords, b_vals, b_coords,
                           *, merge: Callable, cap: int, tiles: Tiles = None):
     return _coo_expand_pl(ends, delta, a_vals, a_coords, b_vals, b_coords,
                           merge=merge, cap=cap, tiles=tiles, interpret=True)
-
-
-@registry.register("coo_expand", registry.TPU)
-def _coo_expand_tpu(ends, delta, a_vals, a_coords, b_vals, b_coords, *,
-                    merge: Callable, cap: int, tiles: Tiles = None):
-    return _coo_expand_pl(ends, delta, a_vals, a_coords, b_vals, b_coords,
-                          merge=merge, cap=cap, tiles=tiles, interpret=False)
 
 
 @registry.register("coo_expand", registry.GPU)
@@ -330,7 +320,8 @@ def masked_matmul(a: jnp.ndarray, b: jnp.ndarray, out_block_mask: jnp.ndarray,
     grid — the paper's "compute only the W×H blocks under nonzero A blocks".
     """
     return registry.dispatch("masked_matmul", a, b, out_block_mask,
-                             backend=_force_to_backend(force),
+                             backend=_force_to_backend(
+                                 "masked_matmul", force),
                              block_size=block_size, tiles=tiles)
 
 
@@ -339,7 +330,8 @@ def merge_join(a: jnp.ndarray, b: jnp.ndarray, mask_a: jnp.ndarray,
                *, block_size: int = 256, force: Optional[str] = None,
                tiles: Tiles = None) -> jnp.ndarray:
     return registry.dispatch("merge_join", a, b, mask_a, mask_b,
-                             backend=_force_to_backend(force),
+                             backend=_force_to_backend(
+                                 "merge_join", force),
                              merge=merge, mode=mode, block_size=block_size,
                              tiles=tiles)
 
@@ -349,7 +341,8 @@ def bloom_probe(words: jnp.ndarray, vals: jnp.ndarray, *,
                 force: Optional[str] = None,
                 tiles: Tiles = None) -> jnp.ndarray:
     return registry.dispatch("bloom_probe", words, vals,
-                             backend=_force_to_backend(force),
+                             backend=_force_to_backend(
+                                 "bloom_probe", force),
                              num_hashes=num_hashes, log2_bits=log2_bits,
                              tiles=tiles)
 
@@ -366,7 +359,8 @@ def coo_expand(ends: jnp.ndarray, delta: jnp.ndarray, a_vals: jnp.ndarray,
     """
     return registry.dispatch("coo_expand", ends, delta, a_vals, a_coords,
                              b_vals, b_coords,
-                             backend=_force_to_backend(force),
+                             backend=_force_to_backend(
+                                 "coo_expand", force),
                              merge=merge, cap=cap, tiles=tiles)
 
 
@@ -380,5 +374,6 @@ def sddmm_agg(sp: jnp.ndarray, w: jnp.ndarray, h: jnp.ndarray,
     (the shapes ``core.executor.agg_dense`` produces for ``AggFn.SUM``).
     """
     return registry.dispatch("sddmm_agg", sp, w, h, out_block_mask,
-                             backend=_force_to_backend(force),
+                             backend=_force_to_backend(
+                                 "sddmm_agg", force),
                              dim=dim, block_size=block_size, tiles=tiles)

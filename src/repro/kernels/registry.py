@@ -10,10 +10,12 @@ implementation at call time from runtime capability detection:
                          the correctness reference every backend is tested
                          against.
 * ``pallas-interpret`` — the Pallas kernel body run by the interpreter;
-                         available wherever ``jax.experimental.pallas``
-                         imports (CPU CI included).
+                         always available (CPU CI included).
 * ``pallas-tpu``       — the compiled Mosaic kernel; available when the
-                         default JAX backend is TPU.
+                         default JAX backend is TPU. Registered only for the
+                         kernels whose bodies Mosaic compiles, and planned
+                         only for block sizes its tiling accepts and for
+                         single-worker plans (see ``accepts``).
 * ``pallas-gpu``       — the same kernel body through the Triton lowering;
                          available when ``jax.experimental.pallas.triton``
                          imports AND the default JAX backend is GPU.
@@ -44,6 +46,7 @@ autotuner (``repro.kernels.autotune``) can drive any backend uniformly.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import threading
 import time
@@ -53,6 +56,8 @@ import jax
 
 from repro.kernels import compat
 from repro.runtime import faults
+
+_LOG = logging.getLogger(__name__)
 
 DENSE = "dense"
 INTERPRET = "pallas-interpret"
@@ -76,6 +81,26 @@ _BACKEND_CHOICE_ENV = "REPRO_BACKEND_CHOICE"
 _AUTOTUNE_ENV = "REPRO_AUTOTUNE"
 _BREAKER_THRESHOLD_ENV = "REPRO_BREAKER_THRESHOLD"
 _BREAKER_COOLDOWN_ENV = "REPRO_BREAKER_COOLDOWN"
+
+# Mosaic tiles f32 blocks in multiples of (8, 128): a kernel whose square
+# block is ``block_size`` on a side compiles only for multiples of 128 (the
+# MXU width).
+TPU_BLOCK_MULTIPLE = 128
+
+
+def accepts(backend: str, block_size: Optional[int],
+            n_workers: int = 1) -> bool:
+    """Whether ``backend`` can run a dispatch at ``block_size`` (None:
+    the kernel takes no block size) inside a plan over ``n_workers``
+    workers. Only ``pallas-tpu`` is constrained: by Mosaic's tiling, and
+    to single-worker plans, because XLA's SPMD partitioner cannot split a
+    Mosaic kernel across a mesh. Resolution passes over a backend that
+    does not accept, at plan time and at dispatch alike, instead of
+    letting it fail and fall back."""
+    if backend != TPU:
+        return True
+    return n_workers == 1 and (block_size is None
+                               or block_size % TPU_BLOCK_MULTIPLE == 0)
 
 
 @dataclasses.dataclass
@@ -153,18 +178,24 @@ def available_backends() -> Tuple[str, ...]:
     tier simply never appears here, so it registers everywhere yet can
     never be dispatched to by accident.
     """
-    out = [DENSE]
-    if compat.has_pallas():
-        out.append(INTERPRET)
-        if jax.default_backend() == "tpu":
-            out.append(TPU)
-        if compat.has_triton() and jax.default_backend() == "gpu":
-            out.append(GPU)
+    out = [DENSE, INTERPRET]
+    if jax.default_backend() == "tpu":
+        out.append(TPU)
+    if compat.has_triton() and jax.default_backend() == "gpu":
+        out.append(GPU)
     return tuple(out)
 
 
-def resolve_backend(name: str, backend: Optional[str] = None) -> str:
-    """Pick the physical backend for one dispatch of kernel ``name``."""
+def resolve_backend(name: str, backend: Optional[str] = None,
+                    block_size: Optional[int] = None,
+                    n_workers: int = 1) -> str:
+    """Pick the physical backend for one dispatch of kernel ``name``.
+
+    ``block_size`` is the dispatch's tile granularity, for kernels that
+    take one, and ``n_workers`` the worker count of the plan it runs in: a
+    native impl that does not accept them is passed over (the dense oracle
+    runs instead), and an explicit pin to such an impl is an error.
+    """
     spec = get(name)
     avail = available_backends()
     choice = backend or os.environ.get(_BACKEND_ENV) or None
@@ -179,9 +210,14 @@ def resolve_backend(name: str, backend: Optional[str] = None) -> str:
             raise KeyError(
                 f"kernel {name!r} has no {choice!r} impl "
                 f"(has {spec.backends()})")
+        if not accepts(choice, block_size, n_workers):
+            raise ValueError(
+                f"kernel {name!r} on {choice!r} does not run at block size "
+                f"{block_size} in a {n_workers}-worker plan")
         return choice
     for native in (TPU, GPU):  # at most one can be available
-        if native in avail and native in spec.impls:
+        if (native in avail and native in spec.impls
+                and accepts(native, block_size, n_workers)):
             return native
     if DENSE not in spec.impls:
         raise KeyError(
@@ -191,6 +227,7 @@ def resolve_backend(name: str, backend: Optional[str] = None) -> str:
 
 
 def planned_backend(name: str, backend: Optional[str] = None, *,
+                    block_size: Optional[int] = None, n_workers: int = 1,
                     cost_model=None, features=None) -> str:
     """Resolve kernel ``name``'s backend at *plan time*.
 
@@ -214,9 +251,11 @@ def planned_backend(name: str, backend: Optional[str] = None, *,
 
     ``features`` is the per-node feature dict (``calibrate.FEATURES``
     keys) describing the work the kernel will do; the builder supplies it
-    from the node's flop/byte annotations.
+    from the node's flop/byte annotations. ``block_size`` and
+    ``n_workers`` are the plan's tile granularity and worker count: an
+    impl that does not accept them is never a candidate.
     """
-    static = resolve_backend(name, backend)
+    static = resolve_backend(name, backend, block_size, n_workers)
     if backend or os.environ.get(_BACKEND_ENV):
         return static  # an explicit pin always wins
     if os.environ.get(_BACKEND_CHOICE_ENV, "").lower() == "static":
@@ -226,7 +265,8 @@ def planned_backend(name: str, backend: Optional[str] = None, *,
     spec = get(name)
     avail = available_backends()
     cands = [b for b in spec.backends()
-             if b in avail and b != INTERPRET]  # interpret is never a plan
+             if b in avail and b != INTERPRET   # interpret is never a plan
+             and accepts(b, block_size, n_workers)]
     if len(cands) < 2:
         return static
     from repro.core import calibrate
@@ -357,15 +397,20 @@ def dispatch(name: str, *args: Any, backend: Optional[str] = None,
     call and feeds the circuit breaker; a quarantined backend is skipped
     outright until its half-open probe re-admits it. Failures of the dense
     oracle itself always propagate — there is nothing left to degrade to.
+    The first failure of each (kernel, backend) pair is logged as a
+    warning with its exception, so a hop never goes unseen.
     """
     spec = get(name)
-    chosen = resolve_backend(name, backend)
+    block_size = kw.get("block_size")
+    chosen = resolve_backend(name, backend, block_size)
     avail = available_backends()
     # the degradation chain: chosen backend first, then its capability-
     # ordered fallbacks (gpu → tpu → dense) restricted to impls this
-    # kernel has and backends this process can run
+    # kernel has, backends this process can run and tilings that accept
+    # this block size
     chain = [chosen] + [fb for fb in _FALLBACK_ORDER.get(chosen, ())
-                        if fb in spec.impls and (fb == DENSE or fb in avail)]
+                        if fb in spec.impls and (fb == DENSE or fb in avail)
+                        and accepts(fb, block_size)]
     # a quarantined head is skipped outright — but never the last resort:
     # with nothing left to degrade to, the quarantined backend still runs
     while len(chain) > 1 and chain[0] != DENSE \
@@ -393,17 +438,20 @@ def dispatch(name: str, *args: Any, backend: Optional[str] = None,
         if b == DENSE:
             if not fallback:
                 faults.check("kernel_dispatch", kernel=name, backend=b)
-            return spec.impls[b](*args, tiles=run_tiles, **kw)
+            out = spec.impls[b](*args, tiles=run_tiles, **kw)
+            _count_dispatch(name, b)
+            return out
         try:
             if not fallback:
                 faults.check("kernel_dispatch", kernel=name, backend=b)
             out = spec.impls[b](*args, tiles=run_tiles, **kw)
-        except Exception:
+        except Exception as exc:
             # deliberate containment, not a swallow: the failure is
-            # counted, feeds the breaker, and execution degrades one hop
-            # down the chain (FaultInjected included — that is how chaos
-            # runs drive the quarantine path)
+            # counted, logged, feeds the breaker, and execution degrades
+            # one hop down the chain (FaultInjected included — that is how
+            # chaos runs drive the quarantine path)
             BREAKER.record_failure(b)
+            _warn_once(name, b, exc)
             if last:
                 raise
             from repro.obs.metrics import REGISTRY
@@ -411,9 +459,30 @@ def dispatch(name: str, *args: Any, backend: Optional[str] = None,
                              backend=b).inc()
             continue
         BREAKER.record_success(b)
+        _count_dispatch(name, b)
         return out
     raise RuntimeError(  # pragma: no cover - chain always ends in a run
         f"kernel {name!r}: no runnable backend in {chain}")
+
+
+def _count_dispatch(name: str, backend: str) -> None:
+    """``kernel_dispatches{kernel,backend}``: which impl actually served
+    each dispatch (inside a staged plan, dispatch runs once per trace)."""
+    from repro.obs.metrics import REGISTRY
+    REGISTRY.counter("kernel_dispatches", kernel=name, backend=backend).inc()
+
+
+_WARNED: set = set()
+_WARNED_LOCK = threading.Lock()
+
+
+def _warn_once(name: str, backend: str, exc: BaseException) -> None:
+    with _WARNED_LOCK:
+        if (name, backend) in _WARNED:
+            return
+        _WARNED.add((name, backend))
+    _LOG.warning("kernel %r failed on backend %r and fell back: %s: %s",
+                 name, backend, type(exc).__name__, exc)
 
 
 def _autotune_enabled() -> bool:

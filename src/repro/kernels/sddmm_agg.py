@@ -32,8 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import compat
-from repro.kernels.compat import pl
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DIMS = ("row", "col", "all")
 
@@ -56,40 +56,33 @@ def sddmm_agg_ref(sp: jnp.ndarray, w: jnp.ndarray, h: jnp.ndarray,
     raise ValueError(f"dim {dim!r} not in {DIMS}")
 
 
-def _row_kernel(mask_ref, sp_ref, w_ref, h_ref, out_ref):
-    @pl.when(pl.program_id(1) == 0)
+def _kernel(live_ref, sp_ref, w_ref, h_ref, out_ref, *, dim: str,
+            gn: int):
+    """One (bm, bn) tile of ``sp ∘ (W·H)`` folded into the accumulator.
+    ``col`` walks the grid transposed (j outer, i inner) so that, for every
+    dim, the accumulator is revisited only along the innermost axis."""
+    if dim == "col":
+        j, i = pl.program_id(0), pl.program_id(1)
+    else:
+        i, j = pl.program_id(0), pl.program_id(1)
+    first = pl.program_id(1) == 0
+    if dim == "all":
+        first = first & (pl.program_id(0) == 0)
+
+    @pl.when(first)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(mask_ref[0, 0])
+    @pl.when(live_ref[i * gn + j] != 0)
     def _accum():
         s = sp_ref[...] * jnp.dot(w_ref[...], h_ref[...],
                                   preferred_element_type=out_ref.dtype)
-        out_ref[...] += jnp.sum(s, axis=1, keepdims=True)
-
-
-def _col_kernel(mask_ref, sp_ref, w_ref, h_ref, out_ref):
-    @pl.when(pl.program_id(1) == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(mask_ref[0, 0])
-    def _accum():
-        s = sp_ref[...] * jnp.dot(w_ref[...], h_ref[...],
-                                  preferred_element_type=out_ref.dtype)
-        out_ref[...] += jnp.sum(s, axis=0, keepdims=True)
-
-
-def _all_kernel(mask_ref, sp_ref, w_ref, h_ref, out_ref):
-    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(mask_ref[0, 0])
-    def _accum():
-        s = sp_ref[...] * jnp.dot(w_ref[...], h_ref[...],
-                                  preferred_element_type=out_ref.dtype)
-        out_ref[...] += jnp.sum(s).reshape(1, 1)
+        if dim == "row":
+            out_ref[...] += jnp.sum(s, axis=1, keepdims=True)
+        elif dim == "col":
+            out_ref[...] += jnp.sum(s, axis=0, keepdims=True)
+        else:
+            out_ref[...] += jnp.sum(s).reshape(1, 1)
 
 
 @functools.partial(
@@ -103,7 +96,8 @@ def sddmm_agg_pallas(sp: jnp.ndarray, w: jnp.ndarray, h: jnp.ndarray,
     output tile grid (M, N multiples of bm/bn — the registry wrapper
     pads). K rides whole into each tile: it is the factor width (small by
     construction in the PNMF pipeline), and keeping it unsplit leaves the
-    grid's sole revisiting axis the reduction axis.
+    grid's sole revisiting axis the reduction axis. The mask rides in SMEM
+    as a flat int32 scalar-prefetch operand.
     """
     m, n = sp.shape
     k = w.shape[1]
@@ -112,46 +106,46 @@ def sddmm_agg_pallas(sp: jnp.ndarray, w: jnp.ndarray, h: jnp.ndarray,
     assert m % bm == 0 and n % bn == 0, (sp.shape, bm, bn)
     gm, gn = m // bm, n // bn
     assert mask.shape == (gm, gn), (mask.shape, (gm, gn))
+    if dim not in DIMS:
+        raise ValueError(f"dim {dim!r} not in {DIMS}")
+    live = jnp.asarray(mask).reshape(-1).astype(jnp.int32)
     out_dtype = jnp.promote_types(sp.dtype, jnp.float32)
 
-    if dim == "row":
-        grid = (gm, gn)
-        kernel, out_shape, out_spec = _row_kernel, (m, 1), pl.BlockSpec(
-            (bm, 1), lambda i, j: (i, 0))
-        maps = dict(mask=lambda i, j: (i, j), sp=lambda i, j: (i, j),
-                    w=lambda i, j: (i, 0), h=lambda i, j: (0, j))
-        sem = ("parallel", "arbitrary")
-    elif dim == "col":
-        # transposed traversal: the row-reduction axis must be innermost
-        # so the (1, bn) accumulator is revisited only across it
+    if dim == "col":
         grid = (gn, gm)
-        kernel, out_shape, out_spec = _col_kernel, (1, n), pl.BlockSpec(
-            (1, bn), lambda j, i: (0, j))
-        maps = dict(mask=lambda j, i: (i, j), sp=lambda j, i: (i, j),
-                    w=lambda j, i: (i, 0), h=lambda j, i: (0, j))
-        sem = ("parallel", "arbitrary")
-    elif dim == "all":
-        grid = (gm, gn)
-        kernel, out_shape, out_spec = _all_kernel, (1, 1), pl.BlockSpec(
-            (1, 1), lambda i, j: (0, 0))
-        maps = dict(mask=lambda i, j: (i, j), sp=lambda i, j: (i, j),
-                    w=lambda i, j: (i, 0), h=lambda i, j: (0, j))
-        sem = ("arbitrary", "arbitrary")
+
+        def ij(a, b):
+            return b, a
     else:
-        raise ValueError(f"dim {dim!r} not in {DIMS}")
+        grid = (gm, gn)
+
+        def ij(a, b):
+            return a, b
+    out_shape, out_block, out_map = {
+        "row": ((m, 1), (bm, 1), lambda i, j: (i, 0)),
+        "col": ((1, n), (1, bn), lambda i, j: (0, j)),
+        "all": ((1, 1), (1, 1), lambda i, j: (0, 0)),
+    }[dim]
+    sem = ("arbitrary", "arbitrary") if dim == "all" \
+        else ("parallel", "arbitrary")
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda a, b, live: index(*ij(a, b)))
 
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), maps["mask"]),
-            pl.BlockSpec((bm, bn), maps["sp"]),
-            pl.BlockSpec((bm, k), maps["w"]),
-            pl.BlockSpec((k, bn), maps["h"]),
-        ],
-        out_specs=out_spec,
+        functools.partial(_kernel, dim=dim, gn=gn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                spec((bm, bn), lambda i, j: (i, j)),    # sp tile
+                spec((bm, k), lambda i, j: (i, 0)),     # W row panel
+                spec((k, bn), lambda i, j: (0, j)),     # H column panel
+            ],
+            out_specs=spec(out_block, out_map),
+        ),
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
         interpret=interpret,
-        **compat.compiler_params_kwargs(dimension_semantics=sem),
-    )(mask, sp, w, h)
+        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
+    )(live, sp, w, h)
     return out.astype(sp.dtype)

@@ -49,6 +49,10 @@ Usage:
     python -m repro.launch.dryrun --all --mesh both --out results/dryrun
 """
 
+# The production meshes are TPU v5e pods; the host devices only stand in
+# for them, so the roofline is priced at that chip's peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _abstract_opt_state(aparams):
     sds = lambda: jax.ShapeDtypeStruct((), jnp.int32)
@@ -177,13 +181,14 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                                    if shape.kind in ("train", "prefill")
                                    else 1)
     mf = rl.model_flops(n_params, n_active, tokens, shape.kind)
-    roof = rl.analyze(stats, mf, n_chips)
+    roof = rl.analyze(stats, mf, n_chips, device_kind=TARGET_DEVICE_KIND)
 
     return dict(
         base,
         status="ok",
         hlo_path=hlo_path,
         n_chips=n_chips,
+        device_kind=TARGET_DEVICE_KIND,
         lower_s=round(t_lower, 2),
         compile_s=round(t_compile, 2),
         params=n_params,

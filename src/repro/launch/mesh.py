@@ -25,12 +25,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     if env:
         shape = tuple(int(x) for x in env.split(","))
         assert len(shape) == len(axes), (shape, axes)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """General mesh for tests / elastic replans."""
-    return jax.make_mesh(shape, axes)
+    """General mesh for tests / elastic replans. Every axis is ``Auto``:
+    the sharding layer (``sharding.ctx``) places arrays with
+    ``NamedSharding``s and sharding constraints, which ``jax.make_mesh``'s
+    default ``Explicit`` axes reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def default_rules(mesh) -> MeshRules:

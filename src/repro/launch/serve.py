@@ -218,6 +218,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=32)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.relational:
         return serve_relational(args)
     return serve_lm(args)

@@ -113,8 +113,7 @@ def apply_moe(p, cfg: ModelConfig, x: jnp.ndarray
         for a in ba:
             n_shards *= mesh.shape[a]
         if ba and g % n_shards == 0:
-            from repro.kernels.compat import shard_map
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda xt_, idx_, gate_, wg_, wu_, wd_: _dispatch_block(
                     xt_, idx_, gate_, wg_, wu_, wd_, m=m, dt=dt, c=c,
                     inside_manual=True),

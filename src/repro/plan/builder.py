@@ -13,7 +13,9 @@ made here, once, at plan time:
 * entry joins (V2V) are cost-gated between Bloom-filtered and plain
   sort-merge (``core.cost.choose_v2v_strategy``);
 * kernel-dispatching nodes are annotated with the registry backend
-  (``kernels.registry.planned_backend``);
+  (``kernels.registry.planned_backend``), which passes over any impl that
+  does not accept the plan's block size or, on a mesh, cannot be
+  partitioned;
 * on a multi-device mesh, joins get the partitioning-scheme pair from the
   paper's communication cost model (``core.partitioner.plan_join_static``).
 """
@@ -263,6 +265,8 @@ class _Builder:
                 "ops": 1.0,
             }
         return registry.planned_backend(kernel, self.kernel_backend,
+                                        block_size=self.block_size,
+                                        n_workers=self.n_workers,
                                         cost_model=self.cost_model,
                                         features=features)
 

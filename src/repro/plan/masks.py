@@ -396,7 +396,8 @@ def _annotate_join(node: P.PhysicalNode, plan: P.PhysicalPlan,
             node.kernel = "bloom_probe"
             if node.backend is None:
                 from repro.kernels import registry
-                node.backend = registry.planned_backend("bloom_probe")
+                node.backend = registry.planned_backend(
+                    "bloom_probe", n_workers=plan.n_workers)
         else:
             # plain sortmerge still runs the fused segment-expand kernel
             # on the device tier; keep the backend threaded so dispatch
@@ -404,7 +405,8 @@ def _annotate_join(node: P.PhysicalNode, plan: P.PhysicalPlan,
             node.kernel = "coo_expand"
             if node.backend is None:
                 from repro.kernels import registry
-                node.backend = registry.planned_backend("coo_expand")
+                node.backend = registry.planned_backend(
+                    "coo_expand", n_workers=plan.n_workers)
 
 
 def _side_caps(node: P.PhysicalNode, plan: P.PhysicalPlan, ch: list,

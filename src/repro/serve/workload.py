@@ -106,12 +106,16 @@ def run_workload(session: Session,
     ticket that never finishes at all (the failure mode the robustness
     tier exists to prevent) is counted in ``hung`` — a chaos gate asserts
     that stays zero.
+
+    ``answers`` maps each template name to the result of its first
+    ticket that completed, for parity checks against serial ``collect``.
     """
     from repro.serve.engine import (
         AdmissionError, DeadlineExceeded, ServeEngine,
     )
 
     tickets = []
+    names = []
     rejected = failures = hung = 0
     with ServeEngine(session, cse=cse, **engine_kw) as eng:
         if warmup:
@@ -122,17 +126,19 @@ def run_workload(session: Session,
                 except Exception:
                     pass        # a faulted warmup must not abort the run
         t0 = time.perf_counter()
-        for tenant, _name, expr in stream:
+        for tenant, name, expr in stream:
             while True:
                 try:
                     tickets.append(eng.submit(expr, tenant=tenant))
+                    names.append(name)
                     break
                 except AdmissionError:
                     rejected += 1       # back off and retry, like a client
                     time.sleep(0.0005)
-        for t in tickets:
+        answers = {}
+        for name, t in zip(names, tickets):
             try:
-                t.result(timeout=300.0)
+                answers.setdefault(name, t.result(timeout=300.0))
             except DeadlineExceeded:
                 failures += 1           # terminal: the engine cancelled it
             except TimeoutError:
@@ -154,4 +160,5 @@ def run_workload(session: Session,
         "failures": failures,
         "hung": hung,
         "stats": snap,
+        "answers": answers,
     }

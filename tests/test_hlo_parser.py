@@ -95,12 +95,20 @@ def test_roofline_terms():
     from repro.analysis.hlo import HloStats
     st = HloStats(flops=197e12, bytes_accessed=819e9,
                   collective_bytes=25e9)
-    r = analyze(st, model_flops_total=197e12 * 256, n_chips=256)
+    r = analyze(st, model_flops_total=197e12 * 256, n_chips=256,
+                device_kind="TPU v5 lite")
     assert abs(r.compute_s - 1.0) < 1e-6
     assert abs(r.memory_s - 1.0) < 1e-6
     assert abs(r.collective_s - 0.5) < 1e-6
     assert r.dominant in ("compute", "memory")
     assert abs(r.mfu - 1.0) < 1e-3
+
+
+def test_roofline_unknown_device_kind_is_an_error():
+    from repro.analysis.hlo import HloStats
+    st = HloStats(flops=1.0, bytes_accessed=1.0, collective_bytes=0.0)
+    with pytest.raises(KeyError):
+        analyze(st, model_flops_total=1.0, n_chips=1, device_kind="cpu")
 
 
 def test_model_flops_moe_discount():

@@ -32,12 +32,20 @@ ALL_KERNELS = ("masked_matmul", "merge_join", "bloom_probe",
                "coo_expand", "sddmm_agg")
 
 
+# the kernels whose bodies Mosaic compiles; bloom_probe and coo_expand
+# gather from 1-D arrays, which it does not lower (docs/kernels.md).
+# GSPMD cannot partition a Mosaic kernel, so mesh plans never pick them.
+TPU_KERNELS = ("masked_matmul", "merge_join", "sddmm_agg")
+
+
 def test_builtin_kernels_registered():
     assert set(registry.kernels()) >= set(ALL_KERNELS)
     for name in ALL_KERNELS:
         spec = registry.get(name)
-        assert set(spec.backends()) == {registry.DENSE, registry.INTERPRET,
-                                        registry.TPU, registry.GPU}
+        want = {registry.DENSE, registry.INTERPRET, registry.GPU}
+        if name in TPU_KERNELS:
+            want.add(registry.TPU)
+        assert set(spec.backends()) == want
 
 
 def test_capability_detection_cpu():
@@ -206,6 +214,77 @@ def test_planned_backend_prices_candidates(monkeypatch):
     lone = _StubModel({dense_key: 0.1})
     assert registry.planned_backend("sddmm_agg", cost_model=lone,
                                     features=feats) == registry.TPU
+
+
+def _tpu_available(monkeypatch):
+    monkeypatch.setattr(
+        registry, "available_backends",
+        lambda: (registry.DENSE, registry.INTERPRET, registry.TPU))
+
+
+@pytest.mark.parametrize("kernel", TPU_KERNELS)
+@pytest.mark.parametrize("block_size,n_workers,want", [
+    (256, 1, registry.TPU),       # the MXU-aligned single-chip plan
+    (128, 1, registry.TPU),
+    (8, 1, registry.DENSE),       # no (8, 128) tiling for an 8² block
+    (200, 1, registry.DENSE),
+    (256, 4, registry.DENSE),     # GSPMD cannot partition Mosaic kernels
+])
+def test_planned_backend_gates_tpu_tiling(monkeypatch, kernel, block_size,
+                                          n_workers, want):
+    _tpu_available(monkeypatch)
+    assert registry.planned_backend(kernel, block_size=block_size,
+                                    n_workers=n_workers) == want
+
+
+def test_pinned_tpu_rejects_untileable_block_size(monkeypatch):
+    _tpu_available(monkeypatch)
+    with pytest.raises(ValueError):
+        registry.resolve_backend("masked_matmul", registry.TPU,
+                                 block_size=8)
+    with pytest.raises(ValueError):
+        registry.resolve_backend("merge_join", registry.TPU,
+                                 block_size=256, n_workers=4)
+
+
+def test_builder_plans_dense_for_untileable_block(monkeypatch, rng):
+    """A plan whose block size no TPU tiling accepts carries ``dense`` on
+    its kernel nodes, so dispatch never tries (and fails) the TPU impl."""
+    from repro.core import Session
+    from tests.conftest import sparse
+    _tpu_available(monkeypatch)
+    for bs, want in ((8, registry.DENSE), (128, registry.TPU)):
+        s = Session(block_size=bs, n_workers=1)
+        X = s.load(sparse(rng, 256, 256, 0.01), "X")
+        W = s.load(rng.normal(size=(256, 8)).astype(np.float32), "W")
+        H = s.load(rng.normal(size=(8, 256)).astype(np.float32), "H")
+        plan = X.emul(W.multiply(H)).physical_plan()
+        backends = {n.backend for n in plan.nodes if n.kernel}
+        assert backends == {want}, (bs, backends)
+
+
+def test_fallback_is_logged_once_per_kernel_backend(monkeypatch, caplog):
+    monkeypatch.setattr(
+        registry, "available_backends",
+        lambda: (registry.DENSE, registry.INTERPRET, registry.TPU))
+    name = "_test_warn_kernel"
+    registry.register(name, registry.DENSE)(lambda *a, tiles=None: "dense")
+
+    def broken(*a, tiles=None):
+        raise RuntimeError("mosaic refused")
+
+    registry.register(name, registry.TPU)(broken)
+    registry.BREAKER.reset()
+    try:
+        with caplog.at_level("WARNING", logger=registry.__name__):
+            for _ in range(2):
+                assert registry.dispatch(name) == "dense"
+        hits = [r for r in caplog.records if name in r.getMessage()]
+        assert len(hits) == 1
+        assert "mosaic refused" in hits[0].getMessage()
+    finally:
+        registry.BREAKER.reset()
+        registry._REGISTRY.pop(name, None)
 
 
 def test_planned_backend_static_without_model():

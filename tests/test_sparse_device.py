@@ -144,14 +144,14 @@ def test_empty_join_dtype_matches_populated(rng):
 # ---------------------------------------------------------------------------
 
 def test_analyze_merge_cached_by_name():
-    """The profile cache keys on the merge-fn NAME: a second analysis under
-    the same name returns the cached profile without re-probing (even if a
-    different callable is supplied — names are the identity contract)."""
+    """The profile cache keys on the merge-fn name AND its callable: a
+    second analysis of the same merge returns the cached profile without
+    re-probing, while a different callable under the same name (every
+    plain callable ``Matrix.join`` wraps is named "f") is probed anew."""
     from repro.core import sparsity as spmod
     from repro.core.sparsity import analyze_merge
 
     name = "cache_probe_test"
-    spmod._CACHE.pop(name, None)
     calls = []
 
     def counting_mul(x, y):
@@ -159,15 +159,19 @@ def test_analyze_merge_cached_by_name():
         return x * y
 
     p1 = analyze_merge(MergeFn(name, counting_mul))
-    assert name in spmod._CACHE
+    assert (name, counting_mul) in spmod._CACHE
     assert p1.inducing_x and p1.inducing_y
     probes = len(calls)
     assert probes > 0
-    # same name, different (non-inducing) fn: cache wins, no new probes
-    p2 = analyze_merge(MergeFn(name, lambda x, y: x + y))
-    assert p2 is p1
+    # same merge again: cache wins, no new probes
+    assert analyze_merge(MergeFn(name, counting_mul)) is p1
     assert len(calls) == probes
-    spmod._CACHE.pop(name, None)
+    # same name, different (non-inducing) fn: its own profile
+    add = lambda x, y: x + y  # noqa: E731
+    p2 = analyze_merge(MergeFn(name, add))
+    assert not p2.inducing_x and not p2.inducing_y
+    spmod._CACHE.pop((name, counting_mul), None)
+    spmod._CACHE.pop((name, add), None)
 
 
 def test_analyze_merge_failing_fn_not_inducing():

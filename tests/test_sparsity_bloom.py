@@ -30,6 +30,15 @@ def test_left_linear_combination():
     assert p.inducing_x and not p.inducing_y
 
 
+def test_same_name_different_merges_profiled_apart():
+    """``Matrix.join`` names every plain callable "f": the profile of one
+    must not be served for another."""
+    add = analyze_merge(MergeFn("f", lambda x, y: x + y))
+    mul = analyze_merge(MergeFn("f", lambda x, y: x * y))
+    assert not add.inducing_x and not add.inducing_y
+    assert mul.inducing_x and mul.inducing_y
+
+
 def test_safe_div_inducing_on_numerator():
     p = analyze_merge(safe_div_merge())
     assert p.inducing_x

@@ -99,10 +99,9 @@ def test_error_feedback_accumulates(rng):
 def test_compressed_psum_single_axis(rng):
     from jax.sharding import Mesh
     import numpy as onp
-    from repro.kernels.compat import shard_map
     mesh = Mesh(onp.array(jax.devices()[:1]), ("dp",))
     x = jnp.asarray(rng.normal(size=(32,)).astype(np.float32))
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda v: comp.compressed_psum(v, "dp"), mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(None),
         out_specs=jax.sharding.PartitionSpec(None)))(x)
