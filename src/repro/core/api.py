@@ -122,7 +122,11 @@ class Session:
         # memo search costs candidates against the bound leaf masks
         self._env_version += 1
         if sparsity is None:
-            sparsity = float(np.asarray(bm.nnz())) / max(1, bm.value.size)
+            from repro.obs.trace import annotate, span
+            with span("d2h", what="nnz", name=name):
+                nnz = np.asarray(bm.nnz())
+                annotate(view_bytes=nnz.nbytes)
+            sparsity = float(nnz) / max(1, bm.value.size)
         return Matrix(self, Leaf(name, bm.shape, sparsity))
 
     def execute(self, plan: Expr, optimize: bool = True,

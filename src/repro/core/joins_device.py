@@ -58,10 +58,13 @@ def coo_to_host(coo: DeviceCOO, shape: Tuple[int, ...]):
     import numpy as np
 
     from repro.core.joins import COOTensor
-    keep = np.asarray(coo.valid)
-    idx = np.asarray(coo.idx)[keep].astype(np.int64)
-    val = np.asarray(coo.val)[keep]
-    return COOTensor(idx, val, shape)
+    from repro.obs.trace import annotate, span
+    with span("d2h", what="coo"):
+        keep = np.asarray(coo.valid)
+        idx = np.asarray(coo.idx)
+        val = np.asarray(coo.val)
+        annotate(view_bytes=keep.nbytes + idx.nbytes + val.nbytes)
+    return COOTensor(idx[keep].astype(np.int64), val[keep], shape)
 
 
 def overflowed(coo: DeviceCOO) -> bool:

@@ -19,6 +19,11 @@ Design constraints (docs/observability.md):
   active on at most one thread at a time — activation is a handoff, not
   sharing — so span mutation is single-threaded per trace while the
   tracer itself serves any number of threads, each with its own stack.
+* **One clock with the device trace.** While a trace is active, every
+  span also enters a ``jax.profiler.TraceAnnotation`` of its name (no
+  attributes), so a profiler capture shows the program's phases on the
+  same clock as the device's ops. Trace roots and ``add_event`` sections
+  are never mirrored.
 """
 from __future__ import annotations
 
@@ -27,6 +32,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -130,20 +137,24 @@ _NOOP = _NoopSpan()
 
 
 class _ActiveSpan:
-    """Context manager that appends a child span to the thread's stack."""
+    """Context manager that appends a child span to the thread's stack
+    and mirrors it as a profiler annotation of the same name."""
 
-    __slots__ = ("_local", "_span")
+    __slots__ = ("_local", "_span", "_mirror")
 
     def __init__(self, local, sp: Span):
         self._local = local
         self._span = sp
+        self._mirror = TraceAnnotation(sp.name)
 
     def __enter__(self) -> Span:
         self._local.stack.append(self._span)
+        self._mirror.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
         self._span.finish()
+        self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._span.attrs["error"] = exc_type.__name__
         popped = self._local.stack.pop()
@@ -211,7 +222,7 @@ class Tracer:
     def active(self) -> bool:
         return bool(getattr(self._local, "stack", None))
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, /, **attrs):
         stack = getattr(self._local, "stack", None)
         if not stack:
             return _NOOP
@@ -227,7 +238,9 @@ class Tracer:
 
     def add_event(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Record an already-measured section (e.g. a batch-level phase
-        timed once and attributed to each traced ticket in the batch)."""
+        timed once and attributed to each traced ticket in the batch).
+        It happened before the call, so it is not mirrored to the
+        profiler."""
         sp = self.current()
         if sp is not None:
             ev = Span(name, t0=t0, attrs=attrs or {})
@@ -243,7 +256,7 @@ class Tracer:
 TRACER = Tracer(sample_rate=float(os.environ.get("REPRO_TRACE_SAMPLE", "0")))
 
 
-def span(name: str, **attrs):
+def span(name: str, /, **attrs):
     """Module-level shorthand over the global tracer — the form every
     instrumentation site uses: ``with span("optimize", search=...):``."""
     return TRACER.span(name, **attrs)

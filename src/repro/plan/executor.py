@@ -420,35 +420,37 @@ def _stage(plan: P.PhysicalPlan, mesh=None):
     def fn(*leaf_vals):
         vals: Dict[int, jnp.ndarray] = {}
         for node in plan.nodes:
-            k = node.kind
-            e = node.expr
-            ch = [vals[c] for c in node.children]
-            if k == P.LEAF:
-                if node.op_id in arg_index:
-                    v = leaf_vals[arg_index[node.op_id]]
+            # device ops carry the plan node that issued them
+            with jax.named_scope(f"{node.kind}{node.op_id}"):
+                k = node.kind
+                e = node.expr
+                ch = [vals[c] for c in node.children]
+                if k == P.LEAF:
+                    if node.op_id in arg_index:
+                        v = leaf_vals[arg_index[node.op_id]]
+                    else:
+                        v = jnp.ones(e.shape, jnp.float32)
+                elif k == P.TRANSPOSE:
+                    v = ch[0].T
+                elif k == P.MATSCALAR:
+                    v = ch[0] + e.beta if e.op is EWOp.ADD else ch[0] * e.beta
+                elif k == P.ELEMWISE:
+                    v = ew_values(e.op, ch[0], ch[1])
+                elif k == P.MATMUL:
+                    v = jnp.dot(ch[0], ch[1],
+                                preferred_element_type=ch[0].dtype)
+                elif k == P.INVERSE:
+                    v = jnp.linalg.inv(ch[0])
+                elif k == P.SELECT:
+                    v = select_dense(ch[0], e.pred)
+                elif k == P.AGG:
+                    v = agg_dense(ch[0], e.fn, e.dim)
+                elif k == P.JOIN:
+                    v = joinsmod.join_dense(ch[0], ch[1], e.pred, e.merge)
                 else:
-                    v = jnp.ones(e.shape, jnp.float32)
-            elif k == P.TRANSPOSE:
-                v = ch[0].T
-            elif k == P.MATSCALAR:
-                v = ch[0] + e.beta if e.op is EWOp.ADD else ch[0] * e.beta
-            elif k == P.ELEMWISE:
-                v = ew_values(e.op, ch[0], ch[1])
-            elif k == P.MATMUL:
-                v = jnp.dot(ch[0], ch[1],
-                            preferred_element_type=ch[0].dtype)
-            elif k == P.INVERSE:
-                v = jnp.linalg.inv(ch[0])
-            elif k == P.SELECT:
-                v = select_dense(ch[0], e.pred)
-            elif k == P.AGG:
-                v = agg_dense(ch[0], e.fn, e.dim)
-            elif k == P.JOIN:
-                v = joinsmod.join_dense(ch[0], ch[1], e.pred, e.merge)
-            else:
-                raise TypeError(f"node kind {k!r} is not jit-stageable")
-            if constraint is not None:
-                v = constraint(node, v)
+                    raise TypeError(f"node kind {k!r} is not jit-stageable")
+                if constraint is not None:
+                    v = constraint(node, v)
             vals[node.op_id] = v
         return vals[plan.root]
 
@@ -605,47 +607,49 @@ def _stage_sparse(plan: P.PhysicalPlan, mesh=None):
     def fn(*leaf_vals):
         vals: Dict[int, Union[jnp.ndarray, joinsdev.DeviceCOO]] = {}
         for node in plan.nodes:
-            k = node.kind
-            e = node.expr
-            ch = [vals[c] for c in node.children]
-            if k == P.LEAF:
-                if node.op_id in arg_index:
-                    v = leaf_vals[arg_index[node.op_id]]
+            # device ops carry the plan node that issued them
+            with jax.named_scope(f"{node.kind}{node.op_id}"):
+                k = node.kind
+                e = node.expr
+                ch = [vals[c] for c in node.children]
+                if k == P.LEAF:
+                    if node.op_id in arg_index:
+                        v = leaf_vals[arg_index[node.op_id]]
+                    else:
+                        v = jnp.ones(e.shape, jnp.float32)
+                elif k == P.TRANSPOSE:
+                    v = ch[0].T
+                elif k == P.MATSCALAR:
+                    v = ch[0] + e.beta if e.op is EWOp.ADD else ch[0] * e.beta
+                elif k == P.ELEMWISE:
+                    v = ew_values(e.op, ch[0], ch[1])
+                elif k == P.MASKED_ELEMWISE:
+                    v = _masked(node, ch[0], ch[1], ch[2])
+                elif k == P.MASKED_AGG:
+                    v = _masked_agg(node, ch[0], ch[1], ch[2])
+                elif k == P.MATMUL:
+                    v = jnp.dot(ch[0], ch[1],
+                                preferred_element_type=ch[0].dtype)
+                elif k == P.INVERSE:
+                    v = jnp.linalg.inv(ch[0])
+                elif k == P.SELECT:
+                    v = select_dense(ch[0], e.pred)
+                elif k == P.AGG:
+                    v = agg_dense(ch[0], e.fn, e.dim)
+                elif k == P.JOIN:
+                    pk = e.pred.kind
+                    if pk in (JoinKind.DIRECT_OVERLAY,
+                              JoinKind.TRANSPOSE_OVERLAY):
+                        v = _overlay(node, ch[0], ch[1])
+                    else:
+                        # COO outputs have no matrix consumers (the builder
+                        # un-stages any such plan), so this is the root
+                        assert node.op_id == plan.root
+                        v = _coo_join(node, ch[0], ch[1])
                 else:
-                    v = jnp.ones(e.shape, jnp.float32)
-            elif k == P.TRANSPOSE:
-                v = ch[0].T
-            elif k == P.MATSCALAR:
-                v = ch[0] + e.beta if e.op is EWOp.ADD else ch[0] * e.beta
-            elif k == P.ELEMWISE:
-                v = ew_values(e.op, ch[0], ch[1])
-            elif k == P.MASKED_ELEMWISE:
-                v = _masked(node, ch[0], ch[1], ch[2])
-            elif k == P.MASKED_AGG:
-                v = _masked_agg(node, ch[0], ch[1], ch[2])
-            elif k == P.MATMUL:
-                v = jnp.dot(ch[0], ch[1],
-                            preferred_element_type=ch[0].dtype)
-            elif k == P.INVERSE:
-                v = jnp.linalg.inv(ch[0])
-            elif k == P.SELECT:
-                v = select_dense(ch[0], e.pred)
-            elif k == P.AGG:
-                v = agg_dense(ch[0], e.fn, e.dim)
-            elif k == P.JOIN:
-                pk = e.pred.kind
-                if pk in (JoinKind.DIRECT_OVERLAY,
-                          JoinKind.TRANSPOSE_OVERLAY):
-                    v = _overlay(node, ch[0], ch[1])
-                else:
-                    # COO outputs have no matrix consumers (the builder
-                    # un-stages any such plan), so this is the root
-                    assert node.op_id == plan.root
-                    v = _coo_join(node, ch[0], ch[1])
-            else:
-                raise TypeError(f"node kind {k!r} is not jit-stageable")
-            if constraint is not None:
-                v = constraint(node, v)
+                    raise TypeError(f"node kind {k!r} is not jit-stageable")
+                if constraint is not None:
+                    v = constraint(node, v)
             vals[node.op_id] = v
         return vals[plan.root]
 

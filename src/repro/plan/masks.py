@@ -46,6 +46,8 @@ from repro.core.matrix import (
 )
 from repro.core.predicates import Field, JoinKind
 from repro.core.sparsity import SparsityProfile, analyze_merge
+from repro.obs.trace import annotate as trace_annotate
+from repro.obs.trace import span
 from repro.plan import ops as P
 
 _CAP_ENV = "REPRO_SPARSE_CAP"
@@ -92,7 +94,9 @@ class _Leaves:
         hit = self._arrays.get(name)
         if hit is None:
             if name in self.env:
-                hit = np.asarray(self.env[name].value)
+                with span("d2h", what="leaf", name=name):
+                    hit = np.asarray(self.env[name].value)
+                    trace_annotate(view_bytes=hit.nbytes)
             elif name.startswith("ones("):
                 hit = np.ones(node.shape, np.float32)
             else:
@@ -107,10 +111,12 @@ class _Leaves:
             return hit
         if name in self.env:
             bm = self.env[name]
-            if bm.block_size == self.bs:
-                hit = np.asarray(bm.block_mask)
-            else:
-                hit = np.asarray(compute_block_mask(bm.value, self.bs))
+            with span("d2h", what="mask", name=name):
+                if bm.block_size == self.bs:
+                    hit = np.asarray(bm.block_mask)
+                else:
+                    hit = np.asarray(compute_block_mask(bm.value, self.bs))
+                trace_annotate(view_bytes=hit.nbytes)
         elif name.startswith("ones("):
             hit = mask_ones(node.shape, self.bs)
         else:
@@ -164,7 +170,10 @@ def _info(node: P.PhysicalNode, plan: P.PhysicalPlan,
 
     if k == P.LEAF:
         mask = leaves.mask(node)
-        nnz = float(np.count_nonzero(leaves.array(node)))
+        arr = leaves.array(node)
+        with span("host_scan", what="nnz", name=node.expr.name,
+                  elements=arr.size):
+            nnz = float(np.count_nonzero(arr))
         return MaskInfo(mask, nnz)
 
     if k == P.TRANSPOSE:
@@ -317,8 +326,11 @@ def _join_capacity(node: P.PhysicalNode, plan: P.PhysicalPlan, ch: list,
     b_node = plan.node(node.children[1])
     if a_node.kind == P.LEAF and b_node.kind == P.LEAF:
         from repro.core.joins_device import exact_capacity
-        cap = exact_capacity(leaves.array(a_node), leaves.array(b_node),
-                             node.expr.pred, prof)
+        a, b = leaves.array(a_node), leaves.array(b_node)
+        with span("host_scan", what="exact_cap",
+                  name=f"{a_node.expr.name},{b_node.expr.name}",
+                  elements=a.size + b.size):
+            cap = exact_capacity(a, b, node.expr.pred, prof)
     else:
         bound = _bound_capacity(node, plan, ch, prof)
         if not np.isfinite(bound):
@@ -344,7 +356,6 @@ def annotate(plan: P.PhysicalPlan, env: Dict[str, BlockMatrix],
     by the optimizer's cost-only dry-lowerings (which pass a shared
     ``leaves`` so candidate plans reuse one set of host views).
     """
-    from repro.obs.trace import span
     leaves = leaves or _Leaves(env, plan.block_size)
     key = fingerprint(plan, env, leaves)
     if plan._mask_key == key and plan._mask_infos is not None:
@@ -429,7 +440,10 @@ def _side_caps(node: P.PhysicalNode, plan: P.PhysicalPlan, ch: list,
         if not skip:
             c = size
         elif cnode.kind == P.LEAF:
-            c = int(np.count_nonzero(leaves.array(cnode)))
+            arr = leaves.array(cnode)
+            with span("host_scan", what="side_cap", name=cnode.expr.name,
+                      elements=arr.size):
+                c = int(np.count_nonzero(arr))
         else:
             c = min(size, int(np.ceil(info.nnz)))
         return round_capacity(c)
