@@ -122,11 +122,12 @@ class Session:
         # memo search costs candidates against the bound leaf masks
         self._env_version += 1
         if sparsity is None:
-            from repro.obs.trace import annotate, span
+            # the binding keeps the exact count, so the mask pass reads it
+            # instead of counting again
+            from repro.obs.trace import span
             with span("d2h", what="nnz", name=name):
-                nnz = np.asarray(bm.nnz())
-                annotate(view_bytes=nnz.nbytes)
-            sparsity = float(nnz) / max(1, bm.value.size)
+                nnz = bm.nnz_count()
+            sparsity = nnz / max(1, bm.value.size)
         return Matrix(self, Leaf(name, bm.shape, sparsity))
 
     def execute(self, plan: Expr, optimize: bool = True,

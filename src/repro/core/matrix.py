@@ -12,6 +12,7 @@ The class is a pytree, so BlockMatrix flows through jit/vmap/shard_map.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -33,13 +34,17 @@ class BlockMatrix:
 
     The mask is computed LAZILY on first access: dense-only pipelines never
     pay the O(mn) mask scan, while the sparsity-aware paths (block-skip
-    joins, masked matmul) get it cached.
+    joins, masked matmul) get it cached. The exact nonzero count
+    (``nnz_count``) is cached the same way, as a host int; it stays out
+    of the pytree, so staged programs never see it.
     """
 
     value: jnp.ndarray            # [m, n]
     _mask: Optional[jnp.ndarray] = None   # [mb, nb] bool (lazy cache)
     block_size: int = DEFAULT_BLOCK
     scheme: str = "xi"            # paper partitioning scheme tag (r/c/b/xi)
+    _nnz: Optional[int] = dataclasses.field(  # exact nnz (lazy host cache)
+        default=None, repr=False, compare=False)
 
     @property
     def block_mask(self) -> jnp.ndarray:
@@ -95,6 +100,18 @@ class BlockMatrix:
     def nnz(self) -> jnp.ndarray:
         return jnp.sum(self.value != 0)
 
+    def nnz_count(self) -> int:
+        """Exact nonzero count as a Python int, counted on the device on
+        first use and kept: ``value`` never changes, so the count holds
+        for the instance's life. Asked for under a trace, it is the
+        traced ``nnz()`` and nothing is kept."""
+        if self._nnz is None:
+            partials = nnz_partials(self.value)
+            if isinstance(partials, jax.core.Tracer):
+                return self.nnz()
+            self._nnz = int(np.asarray(partials).sum(dtype=np.int64))
+        return self._nnz
+
     def nnz_blocks(self) -> jnp.ndarray:
         return jnp.sum(self.block_mask)
 
@@ -103,7 +120,7 @@ class BlockMatrix:
 
     def with_scheme(self, scheme: str) -> "BlockMatrix":
         return BlockMatrix(self.value, self._mask, self.block_size,
-                           scheme)
+                           scheme, self._nnz)
 
     def to_dense(self) -> jnp.ndarray:
         return self.value
@@ -111,6 +128,25 @@ class BlockMatrix:
     # -- mask-consistent rebuild ----------------------------------------------
     def refreshed(self) -> "BlockMatrix":
         return BlockMatrix.from_dense(self.value, self.block_size, self.scheme)
+
+
+_INT32_MAX = 2 ** 31 - 1
+
+
+@functools.partial(jax.jit, static_argnames="limit")
+def nnz_partials(value: jnp.ndarray, limit: int = _INT32_MAX) -> jnp.ndarray:
+    """Nonzero counts of ``value`` [m, n] in groups of whole rows, each
+    group small enough that its count is at most ``limit``; the groups
+    add up to the exact nnz on the host in int64. With x64 off the
+    device sums in int32, which a single sum over a matrix of 2**31
+    entries or more would wrap (a float32 matrix filling a 16-GB chip
+    has 4.3e9)."""
+    m, n = value.shape
+    rows = jnp.sum(value != 0, axis=1)
+    per = max(1, limit // max(1, n))      # rows per group
+    groups = _ceil_div(m, per)
+    rows = jnp.pad(rows, (0, groups * per - m))
+    return rows.reshape(groups, per).sum(axis=1)
 
 
 def compute_block_mask(value: jnp.ndarray, block_size: int) -> jnp.ndarray:

@@ -17,9 +17,15 @@ algebra of ``repro.core.matrix`` and the sparsity-inducing profiles of
   demotion) with per-node numbers instead of leaf-only sparsity products;
 * every COO-producing join gets a **static buffer capacity** for the
   device tier (``repro.core.joins_device``): exact when both inputs are
-  catalog leaves (one O(nnz) host scan), a mask-derived bound otherwise.
+  catalog leaves (one O(nnz log nnz) host scan of their entries, the only
+  host pass over a leaf), a mask-derived bound otherwise.
   Joins whose bound exceeds ``device_cap_limit()`` are marked host-only
   and the whole plan falls back to the eager oracle.
+
+A leaf's nnz is exact and is not counted here: it comes from the binding
+(``BlockMatrix.nnz_count``), counted once on the device — by
+``Session.load``, or on the first read of a binding loaded with a given
+sparsity — and kept until the leaf is rebound.
 
 Results are written into ``node.meta`` (``mask`` / ``nnz_bound`` /
 ``cap`` / ``device`` / ``demote_dense``) and keyed by a fingerprint of
@@ -46,6 +52,7 @@ from repro.core.matrix import (
 )
 from repro.core.predicates import Field, JoinKind
 from repro.core.sparsity import SparsityProfile, analyze_merge
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import annotate as trace_annotate
 from repro.obs.trace import span
 from repro.plan import ops as P
@@ -72,7 +79,9 @@ class MaskInfo:
 # ---------------------------------------------------------------------------
 
 class _Leaves:
-    """Host views of the catalog leaves, fetched lazily and at most once.
+    """Host views of the catalog leaves, fetched lazily and at most once,
+    and their exact nnz, read from the bindings (no host pass: only
+    ``exact_capacity`` scans a leaf's host view, once per join).
 
     An instance may be shared across *many* plans over the same catalog —
     the memo optimizer costs every candidate rewrite of one query against
@@ -103,6 +112,21 @@ class _Leaves:
                 raise KeyError(f"unbound matrix {name!r}")
             self._arrays[name] = hit
         return hit
+
+    def nnz(self, node: P.PhysicalNode) -> int:
+        """Exact nonzero count of a leaf, kept by its binding."""
+        name = node.expr.name
+        if name in self.env:
+            bm = self.env[name]
+            if bm._nnz is not None:
+                REGISTRY.counter("mask_leaf_nnz", source="cached").inc()
+                return bm._nnz
+            REGISTRY.counter("mask_leaf_nnz", source="device").inc()
+            with span("d2h", what="nnz", name=name):
+                return bm.nnz_count()
+        if name.startswith("ones("):
+            return int(np.prod(node.shape))
+        raise KeyError(f"unbound matrix {name!r}")
 
     def mask(self, node: P.PhysicalNode) -> np.ndarray:
         name = node.expr.name
@@ -169,12 +193,7 @@ def _info(node: P.PhysicalNode, plan: P.PhysicalPlan,
     ch = [infos[c] for c in node.children]
 
     if k == P.LEAF:
-        mask = leaves.mask(node)
-        arr = leaves.array(node)
-        with span("host_scan", what="nnz", name=node.expr.name,
-                  elements=arr.size):
-            nnz = float(np.count_nonzero(arr))
-        return MaskInfo(mask, nnz)
+        return MaskInfo(leaves.mask(node), float(leaves.nnz(node)))
 
     if k == P.TRANSPOSE:
         return MaskInfo(ch[0].mask.T.copy(), ch[0].nnz)
@@ -440,10 +459,7 @@ def _side_caps(node: P.PhysicalNode, plan: P.PhysicalPlan, ch: list,
         if not skip:
             c = size
         elif cnode.kind == P.LEAF:
-            arr = leaves.array(cnode)
-            with span("host_scan", what="side_cap", name=cnode.expr.name,
-                      elements=arr.size):
-                c = int(np.count_nonzero(arr))
+            c = leaves.nnz(cnode)
         else:
             c = min(size, int(np.ceil(info.nnz)))
         return round_capacity(c)

@@ -2,9 +2,11 @@
 mirror onto the profiler clock.
 
 ``d2h`` spans time every host view (``np.asarray``) of a catalog leaf, a
-block mask, ``Session.load``'s nnz or a device COO result: each blocks on
-the device, and copies where the array holds no host copy yet.
-``host_scan`` spans time every host pass over a fetched leaf. Both are
+block mask, a binding's nnz or a device COO result: each blocks on the
+device, and copies where the array holds no host copy yet. ``host_scan``
+spans time every host pass over a fetched leaf: only a COO join of two
+leaves makes one (its exact capacity), since a leaf's nnz comes from its
+binding. Both are
 no-ops unless a trace is active. While one is, every span is also a
 ``jax.profiler.TraceAnnotation`` of its name, so a profiler capture shows
 the program's phases beside the device's ops. Staged plans name each
@@ -22,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.core import MergeFn, Session
+from repro.obs.metrics import REGISTRY
 from repro.obs import trace as tracemod
 from repro.obs.trace import TRACER, Tracer
 from repro.plan import masks as masksmod
@@ -102,44 +105,42 @@ def _grid(shape):
     return math.ceil(shape[0] / BS) * math.ceil(shape[1] / BS)
 
 
-def test_rebind_and_collect_fetch_every_leaf_twice(pnmf, monkeypatch):
+def _leaf_nnz_reads():
+    return {src: REGISTRY.counter("mask_leaf_nnz", source=src).value
+            for src in ("cached", "device")}
+
+
+def test_rebind_and_collect_read_leaf_nnz_from_the_binding(pnmf,
+                                                            monkeypatch):
     cat, step = pnmf
     counting = _CountingNumpy()
     monkeypatch.setattr(masksmod, "np", counting)
+    before = _leaf_nnz_reads()
     tr = _traced(step)
+    after = _leaf_nnz_reads()
     spans = _with_ancestors(tr.root)
     d2h = [s for s, _ in spans if s.name == "d2h"]
-    assert {s.attrs["what"] for s in d2h} == {"leaf", "mask", "nnz"}
+    # no host view of a leaf and no host pass over one: the mask pass
+    # reads each leaf's nnz from its binding
+    assert {s.attrs["what"] for s in d2h} == {"mask", "nnz"}
+    assert not [s for s, _ in spans if s.name == "host_scan"]
+    assert counting.elements == 0
 
     def by(what):
         return [s for s in d2h if s.attrs["what"] == what]
 
-    # each leaf twice: the optimizer's shared Leaves and the executor's own
-    assert collections.Counter(s.attrs["name"] for s in by("leaf")) == \
-        {"A": 2, "W": 2, "H": 2}
-    assert sum(s.attrs["view_bytes"] for s in by("leaf")) == \
-        2 * sum(v.nbytes for v in cat.values())
-    # block masks (one bool per block) the same two times
+    # block masks (one bool per block): the optimizer's shared Leaves and
+    # the executor's own
     assert sum(s.attrs["view_bytes"] for s in by("mask")) == \
         2 * sum(_grid(v.shape) for v in cat.values())
-    # the rebind's nnz: one int32 scalar
-    assert [(s.attrs["name"], s.attrs["view_bytes"])
-            for s in by("nnz")] == [("W", 4)]
-
-    scans = [s for s, _ in spans if s.name == "host_scan"]
-    assert {s.attrs["what"] for s in scans} == {"nnz"}
-    assert counting.elements > 0
-    assert sum(s.attrs["elements"] for s in scans) == counting.elements
-    # A: three costed candidates hold it, and the executor's pass
-    assert sum(s.attrs["elements"] for s in scans
-               if s.attrs["name"] == "A") == 4 * cat["A"].size
-
-    # leaf copies and scans happen inside the mask pass, so the planner's
-    # and optimizer's spans hold them
+    # the rebind's count, taken on the device by the load
+    assert [s.attrs["name"] for s in by("nnz")] == ["W"]
     for s, above in spans:
-        if s.name == "host_scan" or (s.name == "d2h"
-                                     and s.attrs["what"] == "leaf"):
-            assert "mask_propagation" in above, (s.name, s.attrs)
+        if s.name == "d2h" and s.attrs["what"] == "nnz":
+            assert "mask_propagation" not in above
+    # every leaf count the mask pass read was already kept
+    assert after["device"] == before["device"]
+    assert after["cached"] > before["cached"]
 
 
 def test_untraced_collect_opens_no_span(pnmf, monkeypatch):
@@ -222,9 +223,10 @@ def test_coo_join_copies_its_result_and_scans_its_leaves():
     for sp in tr.spans():
         if sp.name == "host_scan":
             scans[sp.attrs["what"]] += sp.attrs["elements"]
-    # the exact capacity reads both leaves whole
+    # the exact capacity reads both leaves whole; the side buffers take
+    # the leaves' counts from their bindings
     assert scans["exact_cap"] % (20 * 12 + 20 * 9) == 0
-    assert scans["exact_cap"] > 0 and scans["side_cap"] > 0
+    assert set(scans) == {"exact_cap"} and scans["exact_cap"] > 0
 
 
 def test_mirror_names_only_opened_spans(monkeypatch):
@@ -279,7 +281,8 @@ def test_profiler_capture_nests_spans_in_the_callers_step(pnmf, tmp_path):
             for line in plane.lines for e in line.events]
     (_, lo, hi), = [e for e in host if e[0] == "collect:N"]
     inside = {n for n, s, t in host if lo <= s and t <= hi}
-    assert {"optimize", "mask_propagation", "d2h", "host_scan"} <= inside
+    assert {"optimize", "mask_propagation", "d2h"} <= inside
+    assert "host_scan" not in inside
 
 
 def _staged_program(mode):
