@@ -261,24 +261,46 @@ def _coo_expand_gpu(ends, delta, a_vals, a_coords, b_vals, b_coords, *,
 
 @registry.register("sddmm_agg", registry.DENSE)
 def _sddmm_agg_dense(sp, w, h, out_block_mask, *, dim: str,
-                     block_size: int = 256, tiles: Tiles = None):
+                     block_size: int = 256, w_mask=None, h_mask=None,
+                     tiles: Tiles = None):
     # the factorized form needs no mask: sp's zeros already gate it
     return sddmm_agg_ref(sp, w, h, dim)
 
 
-def _sddmm_agg_pl(sp, w, h, out_block_mask, *, dim, block_size, tiles,
-                  interpret):
+def _host_mask(mask, shape) -> np.ndarray:
+    """A block mask as a host bool array of ``shape`` (blocks past its
+    own shape dead); every block live where the mask is None or traced,
+    which computes every panel: correct, since sp's zeros gate the
+    result."""
+    if mask is None or isinstance(mask, jax.core.Tracer):
+        return np.ones(shape, bool)
+    mk = np.asarray(mask, bool)
+    out = np.zeros(shape, bool)
+    out[:mk.shape[0], :mk.shape[1]] = mk[:shape[0], :shape[1]]
+    return out
+
+
+def _sddmm_agg_pl(sp, w, h, out_block_mask, *, dim, block_size, w_mask,
+                  h_mask, interpret, operands=None):
+    """Pads to whole tiles and panels, and hands the kernel host masks.
+    The K panel is the block size, so W's and H's block masks are the
+    panel masks; a width K up to the block size is one panel of the
+    whole width. ``operands`` is the dtype the W and H panels are
+    multiplied in (None: their own)."""
     m, n = sp.shape
+    k = w.shape[1]
     bs = block_size
+    bk = k if k <= bs else bs
     spp = _pad_to(sp, bs, bs)
-    wp = jnp.pad(w, ((0, spp.shape[0] - m), (0, 0)))
-    hp = jnp.pad(h, ((0, 0), (0, spp.shape[1] - n)))
-    gm, gn = spp.shape[0] // bs, spp.shape[1] // bs
-    mk = jnp.asarray(out_block_mask)
-    if mk.shape != (gm, gn):
-        mk = jnp.pad(mk, ((0, gm - mk.shape[0]), (0, gn - mk.shape[1])))
-    out = sddmm_agg_pallas(spp, wp, hp, mk, dim=dim, bm=bs, bn=bs,
-                           interpret=interpret)
+    wp = _pad_to(w, bs, bk)
+    hp = _pad_to(h, bk, bs)
+    if operands is not None:
+        wp, hp = wp.astype(operands), hp.astype(operands)
+    gm, gn, gk = spp.shape[0] // bs, spp.shape[1] // bs, wp.shape[1] // bk
+    out = sddmm_agg_pallas(spp, wp, hp, _host_mask(out_block_mask, (gm, gn)),
+                           _host_mask(w_mask, (gm, gk)),
+                           _host_mask(h_mask, (gk, gn)), dim=dim, bm=bs,
+                           bn=bs, bk=bk, interpret=interpret)
     if dim == "row":
         return out[:m]
     if dim == "col":
@@ -286,25 +308,43 @@ def _sddmm_agg_pl(sp, w, h, out_block_mask, *, dim, block_size, tiles,
     return out
 
 
+def _default_dot_operands(dtype):
+    """The operand dtype of XLA's default-precision dot on the TPU:
+    bfloat16 for float32, unless a higher matmul precision is
+    configured."""
+    prec = jax.config.jax_default_matmul_precision
+    if dtype == jnp.float32 and prec in (None, "default", "bfloat16",
+                                         "fastest"):
+        return jnp.bfloat16
+    return None
+
+
 @registry.register("sddmm_agg", registry.INTERPRET)
 def _sddmm_agg_interpret(sp, w, h, out_block_mask, *, dim: str,
-                         block_size: int = 256, tiles: Tiles = None):
+                         block_size: int = 256, w_mask=None, h_mask=None,
+                         tiles: Tiles = None):
     return _sddmm_agg_pl(sp, w, h, out_block_mask, dim=dim,
-                         block_size=block_size, tiles=tiles, interpret=True)
+                         block_size=block_size, w_mask=w_mask,
+                         h_mask=h_mask, interpret=True)
 
 
 @registry.register("sddmm_agg", registry.TPU)
 def _sddmm_agg_tpu(sp, w, h, out_block_mask, *, dim: str,
-                   block_size: int = 256, tiles: Tiles = None):
+                   block_size: int = 256, w_mask=None, h_mask=None,
+                   tiles: Tiles = None):
     return _sddmm_agg_pl(sp, w, h, out_block_mask, dim=dim,
-                         block_size=block_size, tiles=tiles, interpret=False)
+                         block_size=block_size, w_mask=w_mask,
+                         h_mask=h_mask, interpret=False,
+                         operands=_default_dot_operands(w.dtype))
 
 
 @registry.register("sddmm_agg", registry.GPU)
 def _sddmm_agg_gpu(sp, w, h, out_block_mask, *, dim: str,
-                   block_size: int = 256, tiles: Tiles = None):
+                   block_size: int = 256, w_mask=None, h_mask=None,
+                   tiles: Tiles = None):
     return _sddmm_agg_pl(sp, w, h, out_block_mask, dim=dim,
-                         block_size=block_size, tiles=tiles, interpret=False)
+                         block_size=block_size, w_mask=w_mask,
+                         h_mask=h_mask, interpret=False)
 
 
 # ---------------------------------------------------------------------------
@@ -366,14 +406,18 @@ def coo_expand(ends: jnp.ndarray, delta: jnp.ndarray, a_vals: jnp.ndarray,
 
 def sddmm_agg(sp: jnp.ndarray, w: jnp.ndarray, h: jnp.ndarray,
               out_block_mask: jnp.ndarray, *, dim: str,
-              block_size: int = 256, force: Optional[str] = None,
+              block_size: int = 256, w_mask=None, h_mask=None,
+              force: Optional[str] = None,
               tiles: Tiles = None) -> jnp.ndarray:
     """SUM-aggregate ``sp ∘ (W×H)`` without materializing the product.
 
     ``dim``: ``"row"`` → [m, 1], ``"col"`` → [1, n], ``"all"`` → [1, 1]
     (the shapes ``core.executor.agg_dense`` produces for ``AggFn.SUM``).
+    ``w_mask`` / ``h_mask``: W's and H's block masks (None: all live);
+    the kernel skips the K panels they prove dead.
     """
     return registry.dispatch("sddmm_agg", sp, w, h, out_block_mask,
                              backend=_force_to_backend(
                                  "sddmm_agg", force),
-                             dim=dim, block_size=block_size, tiles=tiles)
+                             dim=dim, block_size=block_size, w_mask=w_mask,
+                             h_mask=h_mask, tiles=tiles)

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import TRACER, span
 from repro.runtime import faults
 
@@ -223,7 +224,8 @@ class PlanExecutor:
         v = registry.dispatch(
             "sddmm_agg", sp.value, w.value, h.value, sp.block_mask,
             backend=node.backend, dim=_AGG_DIM[e.dim],
-            block_size=plan.block_size)
+            block_size=plan.block_size, w_mask=w.block_mask,
+            h_mask=h.block_mask)
         self._bump("masked_aggs")
         return BlockMatrix.from_dense(v, plan.block_size)
 
@@ -268,12 +270,14 @@ class PlanExecutor:
             plan, fn, leaf_vals, "spmd" if mesh is not None else "plain")
         return dense_join_result(out, plan.block_size)
 
-    def _call_staged(self, plan: P.PhysicalPlan, fn, leaf_vals, key: str):
+    def _call_staged(self, plan: P.PhysicalPlan, fn, leaf_vals, key: str,
+                     **attrs):
         """Dispatch one staged call, attributing its wall time: the first
         call of a freshly-built jit fn is dominated by XLA trace+compile
         (``jax.jit`` compiles lazily) and lands in ``compile_s``; later
         calls are steady-state and land in ``execute_s``. Traced runs
-        synchronize so span/ledger times mean finished work."""
+        synchronize so span/ledger times mean finished work. ``attrs``
+        go on the ``execute`` span."""
         counts = getattr(plan, "_staged_call_counts", None)
         if counts is None:
             counts = plan._staged_call_counts = {}
@@ -282,7 +286,8 @@ class PlanExecutor:
         outer = (TRACER.span("stage_compile", phase="xla-compile")
                  if first else _noop_ctx())
         with outer:
-            with span("execute", path=f"staged-{key}", cold=first):
+            with span("execute", path=f"staged-{key}", cold=first,
+                      **attrs):
                 t0 = time.perf_counter()
                 out = fn(*leaf_vals)
                 if traced:
@@ -334,7 +339,8 @@ class PlanExecutor:
         leaf_vals = tuple(self.env[name].value for name in leaf_names)
         out = self._call_staged(
             plan, fn, leaf_vals,
-            "sparse-spmd" if mesh is not None else "sparse")
+            "sparse-spmd" if mesh is not None else "sparse",
+            **_masked_attrs(plan))
         root = plan.node(plan.root)
         if isinstance(out, joinsdev.DeviceCOO) and joinsdev.overflowed(out):
             # leaf values drifted under an unchanged block mask: the
@@ -375,6 +381,33 @@ def _sync(x) -> None:
         jax.block_until_ready(getattr(x, "value", x))
     except (TypeError, AttributeError):
         pass
+
+
+# the block-skipping kernel an undemoted masked node dispatches
+_MASKED_KERNELS = {P.MASKED_AGG: "sddmm_agg",
+                   P.MASKED_ELEMWISE: "masked_matmul"}
+
+
+def _masked_attrs(plan: P.PhysicalPlan) -> Dict[str, str]:
+    """The ``execute`` span attributes of a staged sparse plan: the
+    kernels its masked nodes dispatch (``kernels``), and which arm of the
+    gate its ``MASKED_AGG`` nodes took (``masked_agg``: ``kernel`` or
+    ``demoted``); neither where the plan has no such node."""
+    kernels, arms = set(), set()
+    for n in plan.nodes:
+        if n.kind not in _MASKED_KERNELS:
+            continue
+        demoted = bool(n.meta.get("demote_dense"))
+        if not demoted:
+            kernels.add(_MASKED_KERNELS[n.kind])
+        if n.kind == P.MASKED_AGG:
+            arms.add("demoted" if demoted else "kernel")
+    attrs = {}
+    if kernels:
+        attrs["kernels"] = ",".join(sorted(kernels))
+    if arms:
+        attrs["masked_agg"] = ",".join(sorted(arms))
+    return attrs
 
 
 class _noop_ctx:
@@ -471,6 +504,7 @@ def _stage_sparse(plan: P.PhysicalPlan, mesh=None):
     from repro.core.sparsity import analyze_merge
     from repro.kernels import registry
     from repro.kernels.merge_join import mode_for
+    from repro.kernels.sddmm_agg import panel_counts
     from repro.core import cost as costmod
     from repro.core.matrix import blocks_of, unblock
     from repro.core.predicates import JoinKind
@@ -494,10 +528,16 @@ def _stage_sparse(plan: P.PhysicalPlan, mesh=None):
         if n.kind == P.MASKED_AGG and not n.meta.get("demote_dense"):
             # the fused kernel's gate is the sparse child's mask (the
             # node's own mask is the tiny aggregation output)
-            g = plan.node(n.children[0]).meta.get("mask")
+            g, wm, hm = (plan.node(c).meta.get("mask") for c in n.children)
             if g is not None:
                 skipped += int(g.size - g.sum())
                 total += int(g.size)
+            if g is not None and wm is not None and hm is not None:
+                computed, dead = panel_counts(g, wm, hm)
+                REGISTRY.counter("sddmm_agg_panels",
+                                 state="computed").inc(computed)
+                REGISTRY.counter("sddmm_agg_panels",
+                                 state="skipped").inc(dead)
     skip_stats = (skipped, total)
 
     constraint = None
@@ -584,10 +624,12 @@ def _stage_sparse(plan: P.PhysicalPlan, mesh=None):
             return agg_dense(sp * jnp.dot(w, h,
                                           preferred_element_type=w.dtype),
                              e.fn, e.dim)
-        gate = jnp.asarray(plan.node(node.children[0]).meta["mask"])
+        # the plan-time masks are host arrays: the kernel lists its live
+        # panel products from them while the program is traced
+        gate, wm, hm = (plan.node(c).meta["mask"] for c in node.children)
         return registry.dispatch(
             "sddmm_agg", sp, w, h, gate, backend=node.backend,
-            dim=_AGG_DIM[e.dim], block_size=bs)
+            dim=_AGG_DIM[e.dim], block_size=bs, w_mask=wm, h_mask=hm)
 
     def _masked(node, sp, w, h):
         e: ElemWise = node.expr

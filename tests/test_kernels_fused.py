@@ -17,6 +17,7 @@ from repro.core.expr import (
 )
 from repro.core.matrix import compute_block_mask
 from repro.kernels import registry
+from repro.kernels.sddmm_agg import panel_steps as sddmm_panel_steps
 from repro.plan import build_plan
 from repro.plan import masks as masksmod
 from repro.plan import ops as P
@@ -219,6 +220,62 @@ def test_sddmm_agg_dead_blocks_do_not_leak(rng):
                             backend=registry.DENSE, dim="row", block_size=bs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
     assert not np.asarray(out)[8:].any()  # dead rows are exactly zero
+
+
+def _paneled_case(rng, m=48, k=80, n=40, bs=16):
+    """K = 80 splits into five panels of 16; about half of W's and H's
+    blocks are dead, and a quarter of sp's."""
+    def blocky(shape, keep, density=1.0):
+        x = np.where(rng.uniform(size=shape) < density,
+                     rng.normal(size=shape), 0.0)
+        gr, gc = -(-shape[0] // bs), -(-shape[1] // bs)
+        live = rng.uniform(size=(gr, gc)) < keep
+        live[0, 0] = True
+        return (x * np.kron(live, np.ones((bs, bs)))[:shape[0], :shape[1]]
+                ).astype(np.float32)
+    sp, w, h = blocky((m, n), 0.75, 0.3), blocky((m, k), 0.5), \
+        blocky((k, n), 0.5)
+    return [jnp.asarray(v) for v in (sp, w, h)], bs
+
+
+@pytest.mark.parametrize("dim", ["row", "col", "all"])
+@pytest.mark.parametrize("chunk", [None, 7])
+def test_sddmm_agg_skips_dead_k_panels(rng, dim, chunk, monkeypatch):
+    """K wider than a panel: the kernel walks only the (I, J, K) panel
+    products whose three blocks are live, in chunks of steps where the
+    list is long, and equals the plain product whether or not it is
+    handed W's and H's masks."""
+    import repro.kernels.sddmm_agg as sddmm
+    if chunk is not None:
+        monkeypatch.setattr(sddmm, "CHUNK_STEPS", chunk)
+    (sp, w, h), bs = _paneled_case(rng)
+    ms, mw, mh = (np.asarray(compute_block_mask(v, bs)) for v in (sp, w, h))
+    computed, skipped = sddmm.panel_counts(ms, mw, mh)
+    assert computed > 0 and skipped > 0        # dead panels exist
+    assert len(sddmm.panel_steps(ms, mw, mh, dim)[0]) == computed
+    prod = np.asarray(sp, np.float64) * (np.asarray(w, np.float64)
+                                         @ np.asarray(h, np.float64))
+    axis = {"row": 1, "col": 0, "all": None}[dim]
+    want = np.sum(prod, axis=axis, keepdims=True).reshape(
+        {"row": (48, 1), "col": (1, 40), "all": (1, 1)}[dim])
+    for masks in ({"w_mask": mw, "h_mask": mh}, {}):
+        got = registry.dispatch("sddmm_agg", sp, w, h, ms,
+                                backend=registry.INTERPRET, dim=dim,
+                                block_size=bs, **masks)
+        # float32 sums of up to 80-term products: rounding only
+        np.testing.assert_allclose(np.asarray(got, np.float64), want,
+                                   atol=5e-4, rtol=1e-4)
+
+
+def test_panel_steps_order_and_count():
+    live = np.array([[1, 0], [1, 1]], bool)
+    i, j, k = sddmm_panel_steps(live, live, live, "row")
+    # (I, J, K) with live(I, J), live(I, K), live(K, J), sorted by I, J, K
+    assert list(zip(i, j, k)) == [(0, 0, 0), (1, 0, 0), (1, 0, 1),
+                                  (1, 1, 1)]
+    i, j, k = sddmm_panel_steps(live, live, live, "col")
+    assert list(zip(j, i, k)) == [(0, 0, 0), (0, 1, 0), (0, 1, 1),
+                                  (1, 1, 1)]
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -19,6 +20,9 @@ from repro.kernels import ops, registry
 N = 4096
 BS = 256
 K = 128
+# triangle counting at Graph500 SCALE 15: sp, W and H are one 32,768²
+# adjacency matrix, so K = n, split into 128 panels of BS
+N_GRAPH = 32768
 
 
 @pytest.fixture(scope="module")
@@ -74,18 +78,31 @@ def _cases():
                 sp, w, h, m, dim=dim, block_size=BS),
             [mat((N, N)), mat((N, K)), mat((K, N)), mat((g, g), bool)])
            for dim in ("row", "col", "all")},
+        "sddmm_agg_triangles": (
+            lambda a: ops._sddmm_agg_tpu(
+                a, a, a, _graph_mask(), dim="row", block_size=BS,
+                w_mask=_graph_mask(), h_mask=_graph_mask()),
+            [mat((N_GRAPH, N_GRAPH))]),
     }
 
 
+def _graph_mask():
+    """40% of the 128 x 128 blocks live, as in a degree-ordered SCALE 15
+    Kronecker graph: 0.4³ of the (I, K, J) panel products, five chunks
+    of grid steps."""
+    g = N_GRAPH // BS
+    return np.random.default_rng(0).uniform(size=(g, g)) < 0.4
+
+
 CASES = ("masked_matmul", "merge_join", "sddmm_agg_row", "sddmm_agg_col",
-         "sddmm_agg_all")
+         "sddmm_agg_all", "sddmm_agg_triangles")
 
 
 def test_cases_cover_every_tpu_registration():
     # built-in kernels only: other tests register "_"-named scratch ones
     tpu = {name for name in registry.kernels() if not name.startswith("_")
            and registry.TPU in registry.get(name).impls}
-    assert tpu == {c.rsplit("_", 1)[0] if c.startswith("sddmm") else c
+    assert tpu == {"sddmm_agg" if c.startswith("sddmm") else c
                    for c in CASES}
 
 
