@@ -79,6 +79,10 @@ class Session:
         self._env_version = 0
         self._plan_cache = VersionedLRU(_PLAN_CACHE_LIMIT)
         self._opt_cache = VersionedLRU(_PLAN_CACHE_LIMIT)
+        # staged programs, keyed on what their trace reads rather than on
+        # the plan: a rebind replans, yet keeps the program when the
+        # trace is unchanged (``repro.plan.executor.program_key``)
+        self._programs = planmod.program_cache()
 
     @property
     def workers(self) -> int:
@@ -147,7 +151,8 @@ class Session:
                                      use_bloom=self.use_bloom)
         pplan = self.physical_plan(plan)
         ex = planmod.PlanExecutor(self.env, mesh=self.mesh,
-                                  metrics=self.metrics)
+                                  metrics=self.metrics,
+                                  programs=self._programs)
         import time
         t0 = time.perf_counter()
         out = ex.run(pplan)
@@ -357,7 +362,8 @@ class Matrix:
             if measure_comm:
                 from repro.plan.executor import staged_collective_bytes
                 measured = staged_collective_bytes(
-                    plan, self.session.env, self.session.mesh)
+                    plan, self.session.env, self.session.mesh,
+                    self.session._programs)
             return planmod.render(plan, measured_bytes=measured,
                                   opt=result) + trace_txt
         return self.optimized_plan().describe(self.plan) + trace_txt

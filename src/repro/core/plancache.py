@@ -12,7 +12,8 @@ one-off under serving churn) and backs every serving-tier cache:
   version-agnostic, which keeps in-flight queries pinned to the version
   they were planned against while new versions warm up alongside.
   Invariant (docs/serving.md): every cache keyed on data-dependent
-  annotations carries the catalog version in its key.
+  annotations carries the catalog version, or those annotations
+  themselves (the staged-program cache), in its key.
 * **thread-safe** — all operations take an internal lock; the serving tier
   hits one shared instance from many worker threads.
 * **per-tenant budgets** — entries are attributed to a tenant; a tenant at

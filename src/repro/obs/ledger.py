@@ -86,16 +86,17 @@ def exec_path_of(stats: Dict[str, int]) -> str:
     return "eager"
 
 
-def measured_comm_bytes(plan, env, mesh) -> Optional[int]:
+def measured_comm_bytes(plan, env, mesh, programs=None) -> Optional[int]:
     """HLO-measured network-wide collective bytes of the staged SPMD
     program, memoized on the plan (compiling + parsing HLO is expensive;
-    the number is a pure function of the staged program)."""
+    the number is a pure function of the staged program). ``programs``
+    is the caller's staged-program cache, which the run filled."""
     cached = getattr(plan, "_measured_comm_bytes", None)
     if cached is not None:
         return cached if cached >= 0 else None
     from repro.plan.executor import staged_collective_bytes
     try:
-        out = staged_collective_bytes(plan, env, mesh)
+        out = staged_collective_bytes(plan, env, mesh, programs)
     except faults.FaultInjected:
         raise                       # injected faults are never swallowed
     except (RuntimeError, ValueError, KeyError, OSError):

@@ -13,8 +13,11 @@ Two paths:
   ``core.joins``), so the DAG executor is value-equivalent by construction;
 * **jit-staged dense** — when every node is jit-safe and the plan was built
   for ``mode="dense"``, the whole DAG is staged into one ``jax.jit``-ed
-  function over the leaf arrays (compiled once per plan, cached on the
-  ``PhysicalPlan``), letting XLA fuse across operators.
+  function over the leaf arrays, letting XLA fuse across operators. A
+  staged program is kept in a cache keyed on what its trace reads
+  (``program_key``), not on the plan object: the session owns one, so a
+  new plan whose trace is unchanged (a leaf rebound to new values of the
+  same shape and block mask) reuses the program instead of restaging.
 
 The staged path has an **SPMD variant**: given a worker mesh (session-owned,
 ``Session.mesh``) and a multi-worker plan, node outputs are pinned to the
@@ -38,8 +41,9 @@ predicted (validated by ``measured_collective_bytes``).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
-from typing import Dict, List, Optional, Tuple, Union
+from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +62,11 @@ from repro.core.executor import (
     select_dense,
 )
 from repro.core.expr import (
-    Agg, AggDim, ElemWise, EWOp, Join, MatScalar, Select,
+    Agg, AggDim, ElemWise, EWOp, Expr, Join, Leaf, MatScalar, Select,
 )
 from repro.core.joins import COOTensor
 from repro.core.matrix import BlockMatrix
+from repro.core.plancache import VersionedLRU
 from repro.plan import ops as P
 
 Result = Union[BlockMatrix, COOTensor]
@@ -80,10 +85,16 @@ class PlanExecutor:
     """
 
     def __init__(self, env: Dict[str, BlockMatrix], stage_jit: bool = True,
-                 mesh=None, node_cache=None, metrics=None):
+                 mesh=None, node_cache=None, metrics=None, programs=None):
         self.env = env
         self.stage_jit = stage_jit
         self.mesh = mesh
+        # staged programs keyed on what their trace reads (``program_key``;
+        # see ``program_cache``): a session passes its own, so a program
+        # outlives the plan object it was built from; an executor built
+        # without one keeps a private cache
+        self.programs = programs if programs is not None \
+            else program_cache()
         # cross-query materialized-result cache (the serving tier's
         # inter-query CSE): an object with ``get(plan, node)`` →
         # result-or-None and ``put(plan, node, result)``. Sharing happens
@@ -245,55 +256,62 @@ class PlanExecutor:
 
     # -- jit-staged dense path ------------------------------------------------
     def _run_staged(self, plan: P.PhysicalPlan, mesh=None) -> Result:
-        staged = plan._staged_spmd_fn if mesh is not None \
-            else plan._staged_fn
-        if staged is None:
-            with span("stage_compile", mode="dense",
-                      spmd=mesh is not None):
-                faults.check("stage_compile", mode="dense",
-                             spmd=mesh is not None)
-                t0 = time.perf_counter()
-                staged = _stage(plan, mesh)
-                self.timings["compile_s"] += time.perf_counter() - t0
-            if mesh is not None:
-                plan._staged_spmd_fn = staged
-            else:
-                plan._staged_fn = staged
-        fn, leaf_names = staged
-        for name in leaf_names:
-            if name not in self.env:
-                raise KeyError(f"unbound matrix {name!r}")
-        leaf_vals = tuple(self.env[name].value for name in leaf_names)
+        path = "spmd" if mesh is not None else "plain"
+        prog = self._program(plan, path, mesh, _stage)
+        leaf_vals = self._leaf_values(prog)
         self._bump("staged_spmd" if mesh is not None else "staged")
         self._bump("node_evals", plan.n_nodes)
-        out = self._call_staged(
-            plan, fn, leaf_vals, "spmd" if mesh is not None else "plain")
+        out = self._call_staged(prog, leaf_vals, path)
         return dense_join_result(out, plan.block_size)
 
-    def _call_staged(self, plan: P.PhysicalPlan, fn, leaf_vals, key: str,
+    def _program(self, plan: P.PhysicalPlan, path: str, mesh,
+                 stage: Callable) -> "_Program":
+        """The staged program of ``plan`` on ``path``: the cached one
+        whose trace is ``plan``'s, else one built by ``stage`` under a
+        ``stage_compile`` span. Bumps ``staged_program{source=built|
+        cached}`` once a staged call."""
+        key = (path, mesh, program_key(plan))
+        prog = self.programs.get(key)
+        source = "cached"
+        if prog is None:
+            spmd = mesh is not None
+            with span("stage_compile", mode=plan.mode, spmd=spmd):
+                faults.check("stage_compile", mode=plan.mode, spmd=spmd)
+                t0 = time.perf_counter()
+                prog = _Program(*stage(_frozen(plan), mesh))
+                self.timings["compile_s"] += time.perf_counter() - t0
+            self.programs.put(key, prog)
+            source = "built"
+        REGISTRY.counter("staged_program", source=source).inc()
+        return prog
+
+    def _leaf_values(self, prog: "_Program") -> tuple:
+        for name in prog.leaf_names:
+            if name not in self.env:
+                raise KeyError(f"unbound matrix {name!r}")
+        return tuple(self.env[name].value for name in prog.leaf_names)
+
+    def _call_staged(self, prog: "_Program", leaf_vals, path: str,
                      **attrs):
-        """Dispatch one staged call, attributing its wall time: the first
-        call of a freshly-built jit fn is dominated by XLA trace+compile
-        (``jax.jit`` compiles lazily) and lands in ``compile_s``; later
-        calls are steady-state and land in ``execute_s``. Traced runs
-        synchronize so span/ledger times mean finished work. ``attrs``
-        go on the ``execute`` span."""
-        counts = getattr(plan, "_staged_call_counts", None)
-        if counts is None:
-            counts = plan._staged_call_counts = {}
-        first = counts.get((key, id(fn)), 0) == 0
+        """Dispatch one staged call, attributing its wall time: the
+        program's first call, on whichever plan, is dominated by XLA
+        trace+compile (``jax.jit`` compiles lazily) and lands in
+        ``compile_s``; later calls are steady-state and land in
+        ``execute_s``. Traced runs synchronize so span/ledger times mean
+        finished work. ``attrs`` go on the ``execute`` span."""
+        first = prog.calls == 0
         traced = TRACER.active()
         outer = (TRACER.span("stage_compile", phase="xla-compile")
                  if first else _noop_ctx())
         with outer:
-            with span("execute", path=f"staged-{key}", cold=first,
+            with span("execute", path=f"staged-{path}", cold=first,
                       **attrs):
                 t0 = time.perf_counter()
-                out = fn(*leaf_vals)
+                out = prog.fn(*leaf_vals)
                 if traced:
                     _sync(out)
                 dt = time.perf_counter() - t0
-        counts[(key, id(fn))] = counts.get((key, id(fn)), 0) + 1
+        prog.calls += 1
         self.timings["compile_s" if first else "execute_s"] += dt
         return out
 
@@ -306,41 +324,13 @@ class PlanExecutor:
         if not masksmod.stageable(plan):
             self._bump("sparse_fallbacks")
             return _FALLBACK
-        slot = "_staged_sparse_spmd_fn" if mesh is not None \
-            else "_staged_sparse_fn"
         # the trace bakes in the propagated masks and the COO capacities
         # (expansion AND side buffers), which can change under an
-        # unchanged expr — key the staged cache on all of them, as a
-        # small map so sessions alternating between leaf bindings don't
-        # retrace on every collect
-        caps = tuple((n.op_id, n.meta.get("cap"), n.meta.get("cap_sides"))
-                     for n in plan.nodes if n.kind == P.JOIN)
-        key = (plan._mask_key, caps)
-        cache = getattr(plan, slot)
-        if cache is None:
-            cache = {}
-            setattr(plan, slot, cache)
-        entry = cache.get(key)
-        if entry is None:
-            while len(cache) >= _STAGED_SPARSE_CACHE_LIMIT:
-                cache.pop(next(iter(cache)))
-            with span("stage_compile", mode="sparse",
-                      spmd=mesh is not None):
-                faults.check("stage_compile", mode="sparse",
-                             spmd=mesh is not None)
-                t0 = time.perf_counter()
-                entry = _stage_sparse(plan, mesh)
-                self.timings["compile_s"] += time.perf_counter() - t0
-            cache[key] = entry
-        fn, leaf_names, skip_stats = entry
-        for name in leaf_names:
-            if name not in self.env:
-                raise KeyError(f"unbound matrix {name!r}")
-        leaf_vals = tuple(self.env[name].value for name in leaf_names)
-        out = self._call_staged(
-            plan, fn, leaf_vals,
-            "sparse-spmd" if mesh is not None else "sparse",
-            **_masked_attrs(plan))
+        # unchanged expr: ``program_key`` holds all of them
+        path = "sparse-spmd" if mesh is not None else "sparse"
+        prog = self._program(plan, path, mesh, _stage_sparse)
+        leaf_vals = self._leaf_values(prog)
+        out = self._call_staged(prog, leaf_vals, path, **_masked_attrs(plan))
         root = plan.node(plan.root)
         if isinstance(out, joinsdev.DeviceCOO) and joinsdev.overflowed(out):
             # leaf values drifted under an unchanged block mask: the
@@ -359,8 +349,8 @@ class PlanExecutor:
         self._bump("masked_matmuls", plan.count(P.MASKED_ELEMWISE))
         self._bump("masked_aggs", plan.count(P.MASKED_AGG))
         self._bump("joins", plan.count(P.JOIN))
-        self._bump("blocks_skipped", skip_stats[0])
-        self._bump("blocks_total", skip_stats[1])
+        self._bump("blocks_skipped", prog.skip_stats[0])
+        self._bump("blocks_total", prog.skip_stats[1])
         if isinstance(out, joinsdev.DeviceCOO):
             return joinsdev.coo_to_host(out, root.shape)
         mask = root.meta.get("mask")
@@ -417,10 +407,87 @@ class _noop_ctx:
     def __exit__(self, *exc):
         return False
 
-# Bounds the per-plan staged-sparse compile cache: each entry pins a jitted
-# executable; sessions alternating among a few leaf bindings stay compiled,
-# pathological churn evicts oldest-first.
-_STAGED_SPARSE_CACHE_LIMIT = 4
+
+# Bounds a staged-program cache: each entry pins a jit-ed executable.
+# Sessions alternating among a few leaf bindings, or serving many
+# queries, stay compiled; churn evicts least-recently-used first.
+PROGRAM_CACHE_LIMIT = 128
+
+
+def program_cache() -> VersionedLRU:
+    """A new staged-program cache, keyed ``(path, mesh, program_key)``
+    (a ``Session`` and a ``ServeEngine`` each own one)."""
+    return VersionedLRU(PROGRAM_CACHE_LIMIT)
+
+
+class _Program:
+    """One staged program: the jit-ed function, the catalog leaves it
+    takes, the static block-gating totals of its trace (sparse tier),
+    and how often it has been called. Its first call, on whichever plan,
+    traces and compiles it (or fetches it from the persistent cache).
+    Only whether ``calls`` is 0 is read, which racing increments from
+    the serving tier's threads cannot undo."""
+
+    __slots__ = ("fn", "leaf_names", "skip_stats", "calls")
+
+    def __init__(self, fn, leaf_names: Tuple[str, ...],
+                 skip_stats: Tuple[int, int] = (0, 0)):
+        self.fn = fn
+        self.leaf_names = leaf_names
+        self.skip_stats = skip_stats
+        self.calls = 0
+
+
+# the flags of ``node.meta`` that ``_stage_sparse`` reads while tracing
+_TRACED_META = ("flip", "demote_dense", "cap", "cap_sides")
+
+
+def program_key(plan: P.PhysicalPlan) -> tuple:
+    """What ``_stage`` / ``_stage_sparse`` read of ``plan`` while
+    tracing, as a key: two plans with equal keys trace the same program.
+
+    Per node: kind, operator payload, wiring, shape, backend, strategy,
+    scheme, the traced ``meta`` flags and the propagated block mask,
+    compared by its bytes (a checksum could collide and skip live
+    blocks). Estimates the trace never reads (a leaf's sparsity,
+    ``est_flops``, ``comm_est``) are left out, and a leaf enters by name
+    and shape: ``jax.jit`` retraces by itself on a new dtype. Kept on the
+    plan until the mask pass re-annotates it (it then writes new
+    ``_mask_infos``), so a cached plan pays one attribute read."""
+    memo = plan._program_key
+    if memo is not None and memo[0] is plan._mask_infos:
+        return memo[1]
+    key = (plan.block_size, plan.mode, plan.root,
+           tuple(_node_key(n) for n in plan.nodes))
+    plan._program_key = (plan._mask_infos, key)
+    return key
+
+
+def _node_key(node: P.PhysicalNode) -> tuple:
+    mask = node.meta.get("mask")
+    if mask is not None:
+        mask = (mask.shape, np.packbits(mask).tobytes())
+    return (node.op_id, node.kind, _payload(node.expr), node.children,
+            node.shape, node.backend, node.strategy, node.scheme,
+            tuple(node.meta.get(k) for k in _TRACED_META), mask)
+
+
+def _payload(e: Expr) -> tuple:
+    """An operator's parameters (scalar, predicate, aggregation, merge):
+    every field of ``e`` but its child expressions; a leaf's name alone."""
+    if isinstance(e, Leaf):
+        return (e.name,)
+    fields = (getattr(e, f.name) for f in dataclasses.fields(e))
+    return (type(e),) + tuple(v for v in fields if not isinstance(v, Expr))
+
+
+def _frozen(plan: P.PhysicalPlan) -> P.PhysicalPlan:
+    """A copy of ``plan`` as it reads now, for a program to trace from: a
+    cached program outlives its plan and serves other plans with the same
+    key, so a later re-annotation of this plan must not reach a retrace."""
+    nodes = tuple(dataclasses.replace(n, meta=dict(n.meta))
+                  for n in plan.nodes)
+    return dataclasses.replace(plan, nodes=nodes)
 
 
 def _stage(plan: P.PhysicalPlan, mesh=None):
@@ -705,17 +772,21 @@ def execute_plan(plan: P.PhysicalPlan, env: Dict[str, BlockMatrix],
 
 def staged_collective_bytes(plan: P.PhysicalPlan,
                             env: Dict[str, BlockMatrix],
-                            mesh) -> Optional[int]:
+                            mesh, programs=None) -> Optional[int]:
     """HLO-measured network-wide collective bytes of the whole-plan SPMD
     program, for validating the scheme pass's ``total_comm_est`` (same
     unit: entries moved × dtype bytes). ``None`` when the plan cannot
-    stage (non-jit-safe or sparse tier)."""
+    stage (non-jit-safe or sparse tier). ``programs``, a session's or
+    engine's program cache, supplies the program and keeps one it builds."""
     if plan.mode != "dense" or not plan.jit_safe or mesh is None:
         return None
     from repro.core.partitioner import measured_network_bytes
-    if plan._staged_spmd_fn is None:
-        plan._staged_spmd_fn = _stage(plan, mesh)
-    fn, leaf_names = plan._staged_spmd_fn
-    leaf_vals = tuple(env[name].value for name in leaf_names)
-    return measured_network_bytes(fn, *leaf_vals,
+    key = ("spmd", mesh, program_key(plan))
+    prog = programs.get(key) if programs is not None else None
+    if prog is None:
+        prog = _Program(*_stage(_frozen(plan), mesh))
+        if programs is not None:
+            programs.put(key, prog)
+    leaf_vals = tuple(env[name].value for name in prog.leaf_names)
+    return measured_network_bytes(prog.fn, *leaf_vals,
                                   n_workers=plan.n_workers)

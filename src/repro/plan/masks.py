@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import zlib
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -152,15 +151,15 @@ class _Leaves:
 def fingerprint(plan: P.PhysicalPlan, env: Dict[str, BlockMatrix],
                 leaves: Optional[_Leaves] = None) -> tuple:
     """Key of the leaf state this annotation was computed from: names,
-    shapes and block-mask bytes (values may drift under the same mask —
-    the runtime overflow guard covers that residual)."""
+    shapes and block-mask bytes, whole (a checksum could collide and
+    keep masks that skip live blocks; values may drift under the same
+    mask — the runtime overflow guard covers that residual)."""
     leaves = leaves or _Leaves(env, plan.block_size)
     parts = []
     for node in plan.nodes:
         if node.kind == P.LEAF:
             m = np.packbits(leaves.mask(node))
-            parts.append((node.expr.name, node.shape,
-                          zlib.crc32(m.tobytes())))
+            parts.append((node.expr.name, node.shape, m.tobytes()))
     return (plan.block_size, tuple(parts))
 
 

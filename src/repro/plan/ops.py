@@ -90,17 +90,11 @@ class PhysicalPlan:
     total_comm_est: float = 0.0        # predicted entries moved, whole plan
     use_bloom: bool = True             # session Bloom preference (V2V gate)
 
-    # staged-execution caches, populated lazily by the DAG executor
-    # (one per path: plain jit, SPMD jit over the session mesh; the sparse
-    # tier additionally keys on the leaf-mask fingerprint — see
-    # ``repro.plan.masks`` — so data changes restage)
-    _staged_fn: Optional[Any] = dataclasses.field(
-        default=None, repr=False, compare=False)
-    _staged_spmd_fn: Optional[Any] = dataclasses.field(
-        default=None, repr=False, compare=False)
-    _staged_sparse_fn: Optional[Any] = dataclasses.field(
-        default=None, repr=False, compare=False)
-    _staged_sparse_spmd_fn: Optional[Any] = dataclasses.field(
+    # staged-program key memo (repro.plan.executor.program_key): what the
+    # trace reads of this plan, kept until the mask pass re-annotates it.
+    # The programs live in a cache the session owns, keyed on it, so a
+    # new plan object whose trace is unchanged reuses its program
+    _program_key: Optional[tuple] = dataclasses.field(
         default=None, repr=False, compare=False)
     # mask-propagation cache (repro.plan.masks.annotate)
     _mask_key: Optional[tuple] = dataclasses.field(

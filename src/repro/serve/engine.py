@@ -51,7 +51,7 @@ from repro.core.plancache import VersionedLRU
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import TRACER
 from repro.plan import builder as buildermod
-from repro.plan.executor import PlanExecutor
+from repro.plan.executor import PlanExecutor, program_cache
 from repro.plan import ops as P
 from repro.runtime import faults
 from repro.runtime.fault_tolerance import (
@@ -279,6 +279,9 @@ class ServeEngine:
         self._refit_last_at = 0
         self._refit_lock = threading.Lock()
         self._refit_thread: Optional[threading.Thread] = None
+        # the engine's staged programs, keyed on what their trace reads:
+        # a program survives a catalog version whose plan traces the same
+        self._programs = program_cache()
         self._results = VersionedLRU(result_entries,
                                      tenant_budget=tenant_result_budget,
                                      name="results", registry=self.metrics)
@@ -850,8 +853,8 @@ class ServeEngine:
             if self.measure_comm:
                 if self.session.mesh is not None:
                     from repro.obs.ledger import measured_comm_bytes
-                    measured_comm = measured_comm_bytes(plan, state.env,
-                                                        self.session.mesh)
+                    measured_comm = measured_comm_bytes(
+                        plan, state.env, self.session.mesh, self._programs)
                 else:
                     # single device: no interconnect, so the measured
                     # collective traffic is exactly zero — recording it
@@ -957,14 +960,15 @@ class ServeEngine:
         identical, slower, and immune to staging failures. Deterministic
         errors (``_NON_RETRYABLE``) propagate immediately.
 
-        The staged compile caches live on the shared ``PhysicalPlan``, so
-        execution is serialized per plan object across worker threads."""
+        The staged executor re-annotates the shared ``PhysicalPlan`` in
+        place (``plan.masks``), so execution is serialized per plan object
+        across worker threads; the programs live in ``self._programs``."""
         with self._lock:
             lock = state.plan_locks.setdefault(id(lw.plan),
                                                threading.Lock())
         for attempt in range(self.exec_retries + 1):
             ex = PlanExecutor(state.env, mesh=self.session.mesh,
-                              metrics=self.metrics)
+                              metrics=self.metrics, programs=self._programs)
             try:
                 with lock:
                     faults.check("execute", attempt=attempt)

@@ -292,11 +292,9 @@ def _staged_program(mode):
     q = _numerator(m)
     q.collect()
     plan = s.physical_plan(s._optimized(q.plan))
-    if mode == "sparse":
-        fn, names, _ = next(iter(plan._staged_sparse_fn.values()))
-    else:
-        fn, names = plan._staged_fn
-    lowered = fn.lower(*(s.env[n].value for n in names))
+    key, = s._programs.keys()
+    prog = s._programs.get(key)
+    lowered = prog.fn.lower(*(s.env[n].value for n in prog.leaf_names))
     return plan, lowered.as_text(debug_info=True)
 
 

@@ -26,7 +26,7 @@ from repro.core.joins import join_sparse, join_sparse_device
 from repro.core.matrix import BlockMatrix, compute_block_mask
 from repro.core.predicates import parse_join
 from repro.core.sparsity import product_merge, sum_merge
-from repro.plan import PlanExecutor
+from repro.plan import PlanExecutor, program_cache
 from repro.plan import masks as masksmod
 
 BS = 8
@@ -211,7 +211,8 @@ def test_mixed_plan_stages_into_one_program(rng):
          .join(a, "RID=RID AND CID=CID", add).sum("r")
     pplan = s.physical_plan(s._optimized(q.plan))
     assert pplan.jit_safe
-    ex = PlanExecutor(s.env)
+    programs = program_cache()
+    ex = PlanExecutor(s.env, programs=programs)
     out = ex.run(pplan)
     assert ex.stats["staged_sparse"] == 1  # ONE compiled program
     assert ex.stats["sparse_fallbacks"] == 0
@@ -219,9 +220,11 @@ def test_mixed_plan_stages_into_one_program(rng):
     np.testing.assert_allclose(np.asarray(out.value),
                                np.asarray(want.value), atol=1e-3, rtol=1e-3)
     # the staged program is cached: a second collect reuses it
-    ex2 = PlanExecutor(s.env)
+    ex2 = PlanExecutor(s.env, programs=programs)
     ex2.run(pplan)
-    assert pplan._staged_sparse_fn is not None
+    assert len(programs) == 1
+    assert ex2.stats["staged_sparse"] == 1
+    assert ex2.timings["compile_s"] == 0.0
 
 
 @pytest.mark.parametrize("pred_s", ["RID=RID", "VAL=VAL", "CROSS",
@@ -316,13 +319,15 @@ def test_side_cap_change_restages(rng):
     s.env["A"] = _bm(a2)
     assert np.array_equal(np.asarray(s.env["A"].block_mask),
                           np.asarray(_bm(a).block_mask))
-    ex2 = PlanExecutor(s.env)
+    ex2 = PlanExecutor(s.env, programs=ex.programs)
     out2 = ex2.run(pplan)               # stale side cap: one fallback
     assert ex2.stats["sparse_overflows"] == 1
-    ex3 = PlanExecutor(s.env)
+    assert len(ex.programs) == 1        # the first program, reused
+    ex3 = PlanExecutor(s.env, programs=ex.programs)
     out3 = ex3.run(pplan)               # re-annotated + restaged
     assert ex3.stats["staged_sparse"] == 1
     assert ex3.stats["sparse_overflows"] == 0
+    assert len(ex.programs) == 2        # grown side caps, a new key
     want = s.execute(q.optimized_plan().plan, optimize=False, engine="tree")
     np.testing.assert_allclose(out2.to_dense(), want.to_dense(), atol=1e-4)
     np.testing.assert_allclose(out3.to_dense(), want.to_dense(), atol=1e-4)
@@ -445,7 +450,7 @@ def test_sparse_plan_stages_spmd_on_mesh(rng):
     ex = PlanExecutor(s.env, mesh=s.mesh)
     out = ex.run(pplan)
     assert ex.stats["staged_sparse_spmd"] == 1     # ONE GSPMD program
-    assert pplan._staged_sparse_spmd_fn is not None
+    assert [key[0] for key in ex.programs.keys()] == ["sparse-spmd"]
     assert pplan.node(pplan.root).scheme is not None  # schemes propagated
     want = s.execute(q.optimized_plan().plan, optimize=False, engine="tree")
     np.testing.assert_allclose(np.asarray(out.value),
