@@ -4,7 +4,7 @@ Usage:
     python benchmarks/check_chaos.py CLEAN.json CHAOS.json \
         [--p99-factor=25] [--expect-restart] [--expect-drops]
 
-Both inputs are ``launch.serve --relational --metrics-out`` dumps (the
+Both inputs are ``launch.serve --metrics-out`` dumps (the
 ``engine`` / ``perf`` / ``faults`` / ``ledger`` sections). The gate
 asserts the robustness contract the chaos CI job exists to enforce:
 

@@ -1,11 +1,11 @@
 """Shared benchmark utilities: timing, synthetic matrices, CSV rows.
 
-Scale note (DESIGN.md §7): the paper benchmarks 100K–200K-dim sparse
-matrices on a 6-node cluster with 1-hour timeouts; this container is one
-CPU core, so benches run reduced dims with the same sparsity regimes and
-validate the paper's *relative* claims (optimized ≪ naive). Cases the paper
-reports as OOM/>1h become 'skipped(cost-model)' rows here — the cost model
-itself predicts infeasibility.
+Scale note: the paper benchmarks 100K–200K-dim sparse matrices on a 6-node
+cluster with 1-hour timeouts; these benches run on one CPU core at reduced
+dims with the same sparsity regimes and validate the paper's *relative*
+claims (optimized ≪ naive). Cases the paper reports as OOM/>1h become
+'skipped(cost-model)' rows here — the cost model itself predicts
+infeasibility.
 """
 from __future__ import annotations
 

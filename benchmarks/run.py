@@ -30,7 +30,6 @@ Prints ``name,us_per_call,derived`` CSV rows. Mapping to the paper:
     bench_dist_comm     — (beyond paper) per-join jit vs whole-plan SPMD
                           (needs XLA_FLAGS=--xla_force_host_platform_
                           device_count=8 on CPU)
-    bench_roofline      — (beyond paper) dry-run roofline table
 
 Usage: ``python benchmarks/run.py [substring] [--json | --json=path]``
 
@@ -94,7 +93,7 @@ def main() -> None:
         bench_agg_gram, bench_cost_model, bench_cross_product,
         bench_dist_comm, bench_join_dims, bench_join_entries,
         bench_join_single, bench_kernels_fused, bench_obs, bench_optimizer,
-        bench_plan_cse, bench_pnmf, bench_robust, bench_roofline,
+        bench_plan_cse, bench_pnmf, bench_robust,
         bench_select_lr, bench_serve, bench_sparse_join,
     )
     from benchmarks.common import ROWS, row
@@ -103,7 +102,7 @@ def main() -> None:
             bench_join_dims, bench_join_single, bench_join_entries,
             bench_pnmf, bench_plan_cse, bench_optimizer, bench_sparse_join,
             bench_serve, bench_obs, bench_robust, bench_cost_model,
-            bench_kernels_fused, bench_dist_comm, bench_roofline]
+            bench_kernels_fused, bench_dist_comm]
     only, json_path = _parse_args(sys.argv[1:])
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -67,7 +67,7 @@ class Expr:
     def with_children(self, *ch: "Expr") -> "Expr":
         raise NotImplementedError
 
-    # small readable repr for plan printing / EXPERIMENTS logs
+    # small readable repr for plan printing
     def pretty(self, indent: int = 0) -> str:
         pad = "  " * indent
         label = self._label()

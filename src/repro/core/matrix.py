@@ -2,10 +2,10 @@
 
 A ``BlockMatrix`` stores a dense backing array plus an explicit block-level
 nonzero mask — the TPU-native analogue of the paper's CSR/CSC local blocks
-(DESIGN.md §2): zero blocks are never touched by the sparsity-aware kernels,
-while nonzero blocks stay dense so the MXU sees aligned tiles. NULL ≡ implicit
-zero, matching the paper's sparse-overlay semantics (Fig. 4; Γnnz counts
-nonzeros, Γavg divides by nnz).
+(docs/sparse.md, "Block-mask algebra"): zero blocks are never touched by the
+sparsity-aware kernels, while nonzero blocks stay dense so the MXU sees
+aligned tiles. NULL ≡ implicit zero, matching the paper's sparse-overlay
+semantics (Fig. 4; Γnnz counts nonzeros, Γavg divides by nnz).
 
 The class is a pytree, so BlockMatrix flows through jit/vmap/shard_map.
 """

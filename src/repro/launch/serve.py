@@ -1,33 +1,17 @@
-"""Serving drivers: the relational query-serving tier and the LM demo.
+"""Serving driver for the relational query-serving tier.
 
-Relational serving (the paper's premise — queries over big matrix data as
-a service): spin a ``ServeEngine`` over a synthetic catalog and serve a
-zipf multi-tenant workload, printing sustained qps and p50/p99 latency
-with and without cross-query CSE:
+Queries over big matrix data as a service: spin a ``ServeEngine`` over a
+synthetic catalog and serve a zipf multi-tenant workload, printing
+sustained qps and p50/p99 latency with and without cross-query CSE:
 
-    PYTHONPATH=src python -m repro.launch.serve --relational \
+    PYTHONPATH=src python -m repro.launch.serve \
         --clients 1000 --dim 48 --threads 2
-
-LM serving (the seed scaffolding, kept): prefill a batch of prompts and
-decode N tokens through the hoisted compiled steps (``repro.serve.step``
-— compiled once per (cfg, shape), decode caches donated):
-
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --smoke \
-        --batch 4 --prompt-len 64 --new-tokens 32
 """
 from __future__ import annotations
 
 import argparse
-import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.configs import get_config, reduced
-from repro.models import api as mapi
-from repro.models.module import init_params
-from repro.serve.step import compiled_decode, compiled_prefill
 
 
 def serve_relational(args) -> int:
@@ -122,60 +106,9 @@ def serve_relational(args) -> int:
     return 0
 
 
-def serve_lm(args) -> int:
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = reduced(cfg)
-    b, s, n_new = args.batch, args.prompt_len, args.new_tokens
-    max_seq = s + n_new + (cfg.n_img_tokens if cfg.family == "vlm" else 0)
-
-    params = init_params(jax.random.key(args.seed), mapi.spec(cfg))
-    rng = np.random.default_rng(args.seed)
-    prompt = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, s)),
-                         jnp.int32)
-    batch = {"tokens": prompt}
-    if cfg.family == "audio":
-        batch["frames"] = jnp.asarray(
-            rng.normal(size=(b, s, cfg.d_model)), jnp.float32)
-    if cfg.family == "vlm":
-        batch["img_embeds"] = jnp.asarray(
-            rng.normal(size=(b, cfg.n_img_tokens, cfg.img_embed_dim)),
-            jnp.float32)
-
-    # hoisted compiled steps: a second driver run in the same process (or
-    # any repro.serve.step.generate call) reuses these executables
-    prefill = compiled_prefill(cfg, max_seq)
-    decode = compiled_decode(cfg, donate=True)
-
-    t0 = time.time()
-    logits, caches = prefill(params, batch)
-    jax.block_until_ready(logits)
-    t_prefill = time.time() - t0
-    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    pos0 = s + (cfg.n_img_tokens if cfg.family == "vlm" else 0)
-    outs = [tok]
-    t0 = time.time()
-    for i in range(n_new - 1):
-        _, tok, caches = decode(params, caches, tok, jnp.int32(pos0 + i))
-        outs.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.time() - t0
-    gen = jnp.concatenate(outs, axis=1)
-    print(f"[serve] arch={cfg.arch_id} batch={b} prompt={s} new={n_new}")
-    print(f"[serve] prefill {t_prefill*1e3:.0f} ms; decode "
-          f"{t_decode/max(1, n_new-1)*1e3:.1f} ms/tok; "
-          f"throughput {(b*(n_new-1))/max(t_decode,1e-9):.1f} tok/s")
-    print(f"[serve] sample tokens: {np.asarray(gen[0, :16])}")
-    return 0
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--relational", action="store_true",
-                    help="serve the relational matrix-query workload "
-                         "instead of the LM demo")
     ap.add_argument("--seed", type=int, default=0)
-    # relational serving
     ap.add_argument("--clients", type=int, default=1000)
     ap.add_argument("--tenants", type=int, default=8)
     ap.add_argument("--dim", type=int, default=48)
@@ -211,18 +144,10 @@ def main(argv=None) -> int:
                          "terminal state and completed+errors == "
                          "submitted (the CI chaos gate; pair with "
                          "REPRO_FAULTS=...)")
-    # LM serving
-    ap.add_argument("--arch", default="qwen3-1.7b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=64)
-    ap.add_argument("--new-tokens", type=int, default=32)
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import setup_compile_cache
     setup_compile_cache()
-    if args.relational:
-        return serve_relational(args)
-    return serve_lm(args)
+    return serve_relational(args)
 
 
 if __name__ == "__main__":

@@ -2,9 +2,9 @@
 
 On a real fleet each host runs a heartbeat agent; the coordinator detects
 missed beats and executes a restart policy (replace from reserve pool, else
-shrink the mesh and elastically restore from the last checkpoint — see
-``checkpoint.ckpt.Checkpointer.restore(shardings=...)``). This module is the
-coordinator logic, fully unit-testable on one host with a simulated clock.
+shrink the mesh and restore from the last checkpoint step the caller names).
+This module is the coordinator logic, fully unit-testable on one host with a
+simulated clock.
 """
 from __future__ import annotations
 

@@ -1,11 +1,8 @@
 """HLO parser: scan trip-count scaling, dot flops, collective bytes."""
 import jax
 import jax.numpy as jnp
-import numpy as np
-import pytest
 
 from repro.analysis.hlo import parse_hlo_module
-from repro.analysis.roofline import analyze, model_flops
 
 
 def _compile(f, *args):
@@ -89,37 +86,6 @@ def test_traffic_fusion_model_chains():
     nbytes = (1 << 20) * 4
     # read x once + write output once (within 3x slack for backend noise)
     assert stats.bytes_accessed <= 3 * 2 * nbytes
-
-
-def test_roofline_terms():
-    from repro.analysis.hlo import HloStats
-    st = HloStats(flops=197e12, bytes_accessed=819e9,
-                  collective_bytes=25e9)
-    r = analyze(st, model_flops_total=197e12 * 256, n_chips=256,
-                device_kind="TPU v5 lite")
-    assert abs(r.compute_s - 1.0) < 1e-6
-    assert abs(r.memory_s - 1.0) < 1e-6
-    assert abs(r.collective_s - 0.5) < 1e-6
-    assert r.dominant in ("compute", "memory")
-    assert abs(r.mfu - 1.0) < 1e-3
-
-
-def test_roofline_unknown_device_kind_is_an_error():
-    from repro.analysis.hlo import HloStats
-    st = HloStats(flops=1.0, bytes_accessed=1.0, collective_bytes=0.0)
-    with pytest.raises(KeyError):
-        analyze(st, model_flops_total=1.0, n_chips=1, device_kind="cpu")
-
-
-def test_model_flops_moe_discount():
-    from repro.analysis.roofline import active_param_count
-    from repro.configs import get_config
-    from repro.models import api as mapi
-    cfg = get_config("mixtral-8x7b")
-    sp = mapi.spec(cfg)
-    total = active_param_count(sp)
-    active = active_param_count(sp, cfg.moe.top_k, cfg.moe.n_experts)
-    assert active < total * 0.45  # 2-of-8 experts + shared attention
 
 
 # ---------------------------------------------------------------------------

@@ -187,3 +187,66 @@ def test_recovered_host_strikes_reset():
     _feed(det, {"w0": 0.1, "w1": 0.1, "w2": 0.1, "w3": 0.1}, n=4)
     det.detect()
     assert det.strikes["w3"] == 0
+
+
+# -- heartbeats / restart policy ----------------------------------------------
+
+def test_heartbeat_failure_detection():
+    t = [0.0]
+    mon = HeartbeatMonitor(["a", "b", "c"], suspect_after=5, fail_after=10,
+                           clock=lambda: t[0])
+    t[0] = 6.0
+    mon.beat("a")
+    mon.sweep()
+    assert mon.nodes["b"].state is NodeState.SUSPECT
+    t[0] = 11.0
+    mon.beat("a")
+    failed = mon.sweep()
+    assert set(failed) == {"b", "c"}
+    assert mon.nodes["a"].state is NodeState.HEALTHY
+
+
+def test_restart_policy_replace_then_shrink():
+    t = [0.0]
+    mon = HeartbeatMonitor(["a", "b", "c", "d"], fail_after=1,
+                           clock=lambda: t[0])
+    co = FaultCoordinator(mon, reserves=["r0"], mesh_granularity=1)
+    t[0] = 2.0
+    mon.beat("a")
+    mon.beat("b")
+    mon.beat("c")
+    mon.sweep()
+    plan = co.plan(last_ckpt_step=42)
+    assert plan.action == "replace" and plan.replacements == ["r0"]
+    assert plan.restore_step == 42
+    # second failure: no reserves left → shrink
+    t[0] = 4.0
+    mon.beat("a")
+    mon.beat("b")
+    mon.beat("r0")
+    mon.sweep()   # c fails
+    plan2 = co.plan()
+    assert plan2.action == "shrink"
+    assert plan2.new_world_size == 3
+
+
+# -- straggler -----------------------------------------------------------------
+
+def test_straggler_detection():
+    hosts = [f"h{i}" for i in range(8)]
+    det = StragglerDetector(hosts, z_threshold=3.0, persist=2)
+    for step in range(6):
+        for h in hosts:
+            det.record(h, 1.0 if h != "h3" else 3.0)
+        rep = det.detect()
+    assert rep.slow_hosts == ["h3"]
+    assert "h3" in rep.reassignment
+
+
+def test_straggler_no_false_positive():
+    hosts = [f"h{i}" for i in range(8)]
+    det = StragglerDetector(hosts)
+    for _ in range(6):
+        for i, h in enumerate(hosts):
+            det.record(h, 1.0 + 0.01 * i)
+    assert det.detect().slow_hosts == []
